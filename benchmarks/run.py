@@ -15,8 +15,23 @@ cost per edge + EM iterations-to-converge).
 import argparse
 import json
 import platform
+import subprocess
 import sys
 import time
+
+
+def _git_rev():
+    """HEAD's commit, asked before JAX loads: a process that holds the
+    chip starts no children."""
+    try:
+        return subprocess.run(
+            ["git", "rev-parse", "HEAD"],
+            capture_output=True,
+            text=True,
+            timeout=30,
+        ).stdout.strip() or None
+    except (OSError, subprocess.SubprocessError):
+        return None
 
 
 def main() -> None:
@@ -30,6 +45,7 @@ def main() -> None:
         help="also write the CSV rows as a JSON trajectory file",
     )
     args = ap.parse_args()
+    git_rev = _git_rev() if args.json else None
 
     from benchmarks import (
         bench_d,
@@ -42,7 +58,9 @@ def main() -> None:
         bench_serve,
         common,
     )
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     suites = {
         "partition": lambda: bench_partition.run(max_d=12 if args.fast else 16),
@@ -64,19 +82,7 @@ def main() -> None:
         fn()
 
     if args.json:
-        import subprocess
-
         import jax
-
-        try:
-            git_rev = subprocess.run(
-                ["git", "rev-parse", "HEAD"],
-                capture_output=True,
-                text=True,
-                timeout=30,
-            ).stdout.strip() or None
-        except (OSError, subprocess.SubprocessError):
-            git_rev = None
 
         record = {
             "schema": "qkg-bench-v1",

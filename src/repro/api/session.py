@@ -474,12 +474,28 @@ class KPGMSampler(_Session):
         backend, or an explicit num_edges over the device budget — the
         host loop honors the target, the engine's host path would not)."""
         if self.plan is None:
+            self._no_plan_fallback()
             return None
         targets = None if num_edges is None else np.array([num_edges])
         try:
             return self._run(key, targets=targets)
-        except quilt.DeviceBatchUnavailable:
+        except quilt.DeviceBatchUnavailable as exc:
+            self._host_fallback(str(exc))
             return None
+
+    def _no_plan_fallback(self) -> None:
+        if self.config.backend != "host":
+            self._host_fallback(
+                f"n={self.n} is over KPGM_PLAN_MAX_NODES="
+                f"{KPGM_PLAN_MAX_NODES}, so there is no device plan"
+            )
+
+    def _host_fallback(self, why: str) -> None:
+        quilt.fallback(
+            quilt.DISPATCH_COUNTERS,
+            "host_fallbacks",
+            f"KPGM sampling on the host loop: {why}",
+        )
 
     def sample(
         self,
@@ -555,11 +571,13 @@ class KPGMSampler(_Session):
         key = self._next_key() if key is None else key
         if num_graphs <= 0:
             return []
-        if self.plan is not None:
+        if self.plan is None:
+            self._no_plan_fallback()
+        else:
             try:
                 run = self._run(key, num_samples=num_graphs)
-            except quilt.DeviceBatchUnavailable:
-                pass
+            except quilt.DeviceBatchUnavailable as exc:
+                self._host_fallback(str(exc))
             else:
                 per = run.edges_per_sample()
                 # key=None: see MAGMSampler.sample_batch — fused members

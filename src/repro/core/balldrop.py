@@ -39,14 +39,12 @@ from __future__ import annotations
 
 import functools
 import math
-import warnings
 from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map as _shard_map
 from repro.core import dedup, kpgm, kron, partition, quilt
 from repro.dist import chaos
 from repro.kernels import ops
@@ -63,6 +61,7 @@ DISPATCH_COUNTERS = {
     "mesh_degrades": 0,
     "degraded_fallbacks": 0,
     "exact_fallbacks": 0,
+    "host_fallbacks": 0,
 }
 
 
@@ -91,14 +90,15 @@ def _bd_round_body(
     rank against the per-sample target.  Returns (snode, dnode, take,
     counts); call under dedup.call_x64.
 
-    ``tables`` selects the rank lookup: ``(table_cfg, table_node)`` for the
-    Pallas kernel, ``(inv,)`` for the dense-inverse gather, or the
-    ``(cfg_offset, cfg_count, cfg_nodes)`` by-config triple — the
-    heavy-config short-circuit, where rank kb hits config x iff
-    ``kb < c_x`` and indexes straight into x's node group (bit-identical
-    to the dense inverse via the stable occurrence-rank order, but
-    O(2^d + n) memory instead of O(B * 2^d), the win for skewed mu where
-    B = c_max is large).
+    ``use_kernel`` picks the Pallas descent kernel (``ranks=True`` emits
+    the rank channels too) or its bit-identical jnp twin.  ``tables``
+    (``quilt.device_lookup``) selects the rank lookup: ``(inv,)`` for the
+    dense-inverse gather, or the ``(cfg_offset, cfg_count, cfg_nodes)``
+    by-config triple — the heavy-config short-circuit, where rank kb hits
+    config x iff ``kb < c_x`` and indexes straight into x's node group
+    (bit-identical to the dense inverse via the stable occurrence-rank
+    order, but O(2^d + n) memory instead of O(B * 2^d), the win for skewed
+    mu where B = c_max is large).
 
     ``exact=True`` composes the per-NODE-pair acceptance thinning of
     ``quilt._exact_cell_valid`` into the valid mask (pi = p_xy / (S B^2)
@@ -109,15 +109,10 @@ def _bd_round_body(
     gc = gids.shape[0]
     a_tot = int(sum(rounds))
     seed = ops.counter_seed(rkey)
-    local = (jnp.arange(gc * a_tot, dtype=jnp.int32) // a_tot).astype(
-        jnp.int32
-    )
-    gid = gids[local]
+    local, gid = quilt.graph_major(gids, a_tot)
     if use_kernel:
-        table_cfg, table_node = tables
-        scfg, dcfg, snode, dnode = ops.quilt_prng_descent_lookup_pallas(
-            seed, gids, cum, table_cfg, table_node,
-            a_tot=a_tot, num_blocks=num_blocks, ranks=True,
+        scfg, dcfg, kb, lb = ops.descent_prng_pallas(
+            seed, gids, cum, a_tot=a_tot, num_blocks=num_blocks, ranks=True,
         )
     else:
         slot = jnp.arange(gc * a_tot, dtype=jnp.int32) - local * a_tot
@@ -125,22 +120,8 @@ def _bd_round_body(
         kb, lb = ops.rank_pair(
             seed[0, 0], seed[0, 1], gid, slot, num_blocks
         )
-        if len(tables) == 3:
-            # by-config short-circuit: rank kb names config x's kb-th node
-            # directly (hit iff kb < c_x), no block table at all
-            cfg_offset, cfg_count, cfg_nodes = tables
-            scfg, dcfg = kpgm._descend(u, cum)
-            cs, cd = cfg_count[scfg], cfg_count[dcfg]
-            idx_s = cfg_offset[scfg] + jnp.minimum(kb, jnp.maximum(cs - 1, 0))
-            idx_d = cfg_offset[dcfg] + jnp.minimum(lb, jnp.maximum(cd - 1, 0))
-            snode = jnp.where(kb < cs, cfg_nodes[idx_s], jnp.int32(-1))
-            dnode = jnp.where(lb < cd, cfg_nodes[idx_d], jnp.int32(-1))
-        else:
-            (inv,) = tables
-            scfg, dcfg = kpgm._descend(u, cum)
-            flat = inv.reshape(-1)
-            snode = flat[(kb << d) | scfg]
-            dnode = flat[(lb << d) | dcfg]
+        scfg, dcfg = kpgm._descend(u, cum)
+    snode, dnode = quilt.gather_nodes(tables, kb, lb, scfg, dcfg, d)
     valid = (snode >= 0) & (dnode >= 0)
     if exact:
         pair = snode.astype(jnp.int64) * jnp.int64(
@@ -159,7 +140,7 @@ def _bd_round_body(
     cum_asks = jnp.arange(1, gc + 1, dtype=jnp.int32) * a_tot
     take, counts = dedup.segmented_unique_mask(
         local, snode, dnode, cum_asks, targets,
-        node_bits=node_bits, valid=valid,
+        node_bits=node_bits, valid=valid, max_ask=a_tot,
     )
     return snode, dnode, take, counts
 
@@ -187,12 +168,12 @@ def _compiled_bd_round(
     if mesh is not None:
         spec = jax.sharding.PartitionSpec(axes)
         rep = jax.sharding.PartitionSpec()
-        body = _shard_map(
+        body = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(rep, spec, spec, rep, rep, (rep,) * num_tables),
             out_specs=(spec,) * 4,
-            check_rep=False,
+            check_vma=False,
         )
     return jax.jit(body)
 
@@ -358,11 +339,9 @@ def balldrop_run(
 
     if use_kernel is None:
         use_kernel = not ops.INTERPRET
-    # rank-lookup preference off-kernel: dense inverse (one gather) when it
-    # exists, else the by-config short-circuit (O(2^d + n) memory) — only
-    # force the kernel when neither table was built
-    if not use_kernel and plan.inv is None and plan.cfg_offset is None:
-        use_kernel = True
+    # rank lookup: dense inverse (one gather) when it exists, else the
+    # by-config short-circuit (O(2^d + n) memory)
+    lookup = quilt.device_lookup(plan)
 
     exact = (not targets_given) if exact_cells is None else bool(exact_cells)
     exact = exact and not targets_given and plan.B > 0 and S > 0
@@ -375,7 +354,14 @@ def balldrop_run(
             plan.p_max, plan.mean_edges * float(plan.B) ** 2
         )
         if budget is None or S * budget > kpgm.DEVICE_MAX_CANDIDATES:
-            DISPATCH_COUNTERS["exact_fallbacks"] += 1
+            quilt.fallback(
+                DISPATCH_COUNTERS,
+                "exact_fallbacks",
+                f"exact-cell ball-dropping round over the device budget ({S}"
+                f" samples x {budget} proposals > DEVICE_MAX_CANDIDATES="
+                f"{kpgm.DEVICE_MAX_CANDIDATES}, or no finite budget): "
+                "taking the drawn-target rounds instead",
+            )
             exact = False
             budget = None
 
@@ -405,13 +391,23 @@ def balldrop_run(
         else dedup.uniform_ask(targets, oversample * plan.bd_cost)
     )
     # layout-invariant device decision, like quilt_run's (S, not s_pad)
-    use_device = exact or S * ask0 <= kpgm.DEVICE_MAX_CANDIDATES
+    use_device = lookup is not None and (
+        exact
+        or (S * ask0 <= kpgm.DEVICE_MAX_CANDIDATES and quilt.slots_fit(ask0))
+    )
     if not use_device:
         if S > 1:
             raise quilt.DeviceBatchUnavailable(
                 "fused balldrop sample_batch over the device budget "
                 f"(candidates={S * ask0})"
             )
+        quilt.fallback(
+            DISPATCH_COUNTERS,
+            "host_fallbacks",
+            f"ball dropping on the host: {S * ask0} candidates is over "
+            f"DEVICE_MAX_CANDIDATES={kpgm.DEVICE_MAX_CANDIDATES} or the "
+            "counter-PRNG slot limit, or the plan has no device lookup",
+        )
         edges = _balldrop_sample_host(
             key,
             plan,
@@ -443,12 +439,7 @@ def balldrop_run(
 
     if total > 0:
         gids_j, tpad_j = quilt._pad_inputs(S, s_pad, targets)
-        if use_kernel:
-            tables = (plan.table_cfg, plan.table_node)
-        elif plan.inv is not None:
-            tables = (plan.inv,)
-        else:
-            tables = (plan.cfg_offset, plan.cfg_count, plan.cfg_nodes)
+        tables = lookup
         rounds: Tuple[int, ...] = ()
         for r in range(1 if exact else max_rounds):
             chaos.maybe_fail("quilt.round")
@@ -458,7 +449,10 @@ def balldrop_run(
             )
             if ask == 0:
                 break
-            if rounds and S * (sum(rounds) + ask) > kpgm.DEVICE_MAX_CANDIDATES:
+            if rounds and (
+                S * (sum(rounds) + ask) > kpgm.DEVICE_MAX_CANDIDATES
+                or not quilt.slots_fit(sum(rounds) + ask)
+            ):
                 # cumulative stream would outgrow the device budget: let
                 # the host top-up finish the residual (layout-invariant,
                 # like quilt_run's guard)
@@ -500,15 +494,14 @@ def balldrop_run(
         # rows are accepted balls: keep == take (and counts == keep sums)
         keep = jax.device_get(take)
         if shortfall.max(initial=0) > 0:
-            DISPATCH_COUNTERS["degraded_fallbacks"] += 1
-            warnings.warn(
+            quilt.fallback(
+                DISPATCH_COUNTERS,
+                "degraded_fallbacks",
                 f"device rounds exhausted (max_rounds={max_rounds}, "
                 f"{a_tot} slots/sample) with {int(shortfall.sum())} edges "
                 "still short: finishing the residual with the host "
                 "ball-dropping loop (raise max_rounds or oversample to "
                 "stay device-resident)",
-                RuntimeWarning,
-                stacklevel=2,
             )
             flat_taken = (
                 jax.device_get(snode)[keep].astype(np.int64) * n

@@ -45,7 +45,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 __all__ = [
     "bucket_size",
@@ -206,17 +205,34 @@ def iter_edge_chunks(
 
     def pieces():
         window = max(int(chunk_edges), 1 << 15)
-        for lo in range(0, keep.shape[0], window):
-            k = keep[lo : lo + window]
-            if not k.any():
-                continue
-            s = jax.device_get(src[lo : lo + window])[k]
-            d = jax.device_get(dst[lo : lo + window])[k]
-            yield np.stack([s, d], axis=1)
+        for (base, s_blk), (_, d_blk) in zip(
+            _row_blocks(src), _row_blocks(dst)
+        ):
+            for off in range(0, s_blk.shape[0], window):
+                k = keep[base + off : base + min(off + window, s_blk.shape[0])]
+                if not k.any():
+                    continue
+                s = jax.device_get(s_blk[off : off + window])[k]
+                d = jax.device_get(d_blk[off : off + window])[k]
+                yield np.stack([s, d], axis=1)
         for t in tail:
             yield t
 
     return rechunk_edges(pieces(), chunk_edges)
+
+
+def _row_blocks(x):
+    """``(row offset, single-device array)`` blocks of a 1-D candidate
+    buffer in row order: the buffer itself, or — when it is sharded over
+    the ``graphs`` axis — each device's shard, so no window slices across
+    devices."""
+    shards = getattr(x, "addressable_shards", None)
+    if not shards or len(shards) == 1:
+        return [(0, x)]
+    blocks = {}
+    for shard in shards:
+        blocks.setdefault(shard.index[0].start or 0, shard.data)
+    return sorted(blocks.items(), key=lambda b: b[0])
 
 
 def _packed_bits(node_bits: int, num_graphs: int, n: int) -> Tuple[int, int, bool]:
@@ -234,6 +250,7 @@ def segmented_unique_mask(
     targets: jax.Array,
     *,
     node_bits: int,
+    max_ask: int,
     valid: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-graph first-occurrence mask with arrival-order target capping.
@@ -251,6 +268,13 @@ def segmented_unique_mask(
     sentinel pair before packing (one extra bit per node id, so their
     ``src``/``dst`` values, -1 included, never collide with real edges) and
     are never fresh, so the per-graph target is filled by valid pairs only.
+
+    ``max_ask`` (static) bounds every chunk's length.  The packed key
+    carries the candidate's position WITHIN its graph, not its global
+    arrival index — the graph bits already order the chunks — so it needs
+    ``log2(max_ask)`` position bits, which keeps large rounds on the single
+    int64 sort (the 4-operand fallback costs far more to compile for a
+    TPU).  ``max_ask = len(src)`` is always a valid bound.
     """
     n = src.shape[0]
     num_graphs = targets.shape[0]
@@ -260,22 +284,26 @@ def segmented_unique_mask(
         src = jnp.where(valid, src.astype(jnp.int32), sentinel)
         dst = jnp.where(valid, dst.astype(jnp.int32), sentinel)
         node_bits = node_bits + 1
-    _, abits, fits = _packed_bits(node_bits, num_graphs, n)
     arrival = jnp.arange(n, dtype=jnp.int64)
+    offs_ex = jnp.concatenate([jnp.zeros((1,), cum_asks.dtype), cum_asks[:-1]])
+    _, abits, fits = _packed_bits(node_bits, num_graphs, max_ask)
+    pos = arrival - offs_ex[graph_id].astype(jnp.int64)
 
     if fits:
         key = (
             (graph_id.astype(jnp.int64) << (2 * node_bits + abits))
             | (src.astype(jnp.int64) << (node_bits + abits))
             | (dst.astype(jnp.int64) << abits)
-            | arrival
+            | pos
         )
         ks = jnp.sort(key)
-        edge = ks >> abits  # (graph, src, dst) with arrival stripped
+        edge = ks >> abits  # (graph, src, dst) with the position stripped
         first = jnp.concatenate(
             [jnp.ones((1,), bool), edge[1:] != edge[:-1]]
         )
-        arr_sorted = (ks & ((jnp.int64(1) << abits) - 1)).astype(jnp.int32)
+        pos_sorted = (ks & ((jnp.int64(1) << abits) - 1)).astype(jnp.int32)
+        g_sorted = (ks >> (2 * node_bits + abits)).astype(jnp.int32)
+        arr_sorted = pos_sorted + offs_ex[g_sorted].astype(jnp.int32)
     else:
         gs, ss, ds, arr_s = jax.lax.sort(
             (
@@ -303,9 +331,6 @@ def segmented_unique_mask(
 
     c = jnp.cumsum(fresh.astype(jnp.int32))
     ends = jnp.maximum(cum_asks - 1, 0)
-    offs_ex = jnp.concatenate(
-        [jnp.zeros((1,), cum_asks.dtype), cum_asks[:-1]]
-    )
     base = jnp.where(offs_ex > 0, c[jnp.maximum(offs_ex - 1, 0)], 0)
     rank = c - base[graph_id]  # 1-based rank among fresh, per graph
     take = fresh & (rank <= targets[graph_id])
@@ -324,7 +349,7 @@ def _segmented_unique_jit(src, dst, asks, targets, *, node_bits):
         cum_asks, jnp.arange(n, dtype=asks.dtype), side="right"
     ).astype(jnp.int32)
     return segmented_unique_mask(
-        graph_id, src, dst, cum_asks, targets, node_bits=node_bits
+        graph_id, src, dst, cum_asks, targets, node_bits=node_bits, max_ask=n
     )
 
 
@@ -334,7 +359,7 @@ def call_x64(fn, *args, **kwargs):
     All dtypes inside the traced code are pinned explicitly, so the context
     only makes int64 available — inputs/outputs keep their 32-bit dtypes.
     """
-    with enable_x64():
+    with jax.enable_x64(True):
         return fn(*args, **kwargs)
 
 

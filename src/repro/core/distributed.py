@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core import kpgm
 
 
@@ -48,7 +47,7 @@ def sample_edges_sharded(
     flat_mesh = Mesh(
         np.asarray(mesh.devices).reshape(-1), axis_names=("dev",)
     )
-    body = _shard_map(
+    body = jax.shard_map(
         functools.partial(_device_sample, per_device=per_device),
         mesh=flat_mesh,
         in_specs=(P(), P()),

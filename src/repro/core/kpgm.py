@@ -26,10 +26,18 @@ import numpy as np
 
 from repro.core import dedup
 
-# Above this many candidates in one device batch the fixed-shape dedup
-# buffers stop paying for themselves on small hosts; fall back to the
-# round-by-round host path (kept for reference + large-d correctness).
-DEVICE_MAX_CANDIDATES = 1 << 25
+# Most candidates one device round may hold, sized for one TPU v5e (16 GiB
+# HBM).  Every round that reads it, compiled for a v5e at n = 2^16 widths
+# and this many candidates, needs by compiled.memory_analysis() (temp +
+# outputs + arguments): the exact-cell quilt round over 64 graphs 1.189e10 B
+# (11.07 GiB, 118 B each), a fused ball-dropping round of 64 samples
+# 1.108e10 B, kpgm_sample_many's fused round 3.73e9 B, and the split heavy
+# round at its 2^26-slot limit 1.95e9 B — leaving room for the plan and the
+# previous round's buffers (tests/test_chip_compile.py holds each under 85%
+# of the HBM).  Past the cap a run fails loudly or takes a counted, warned
+# fallback.  (The counter-PRNG word bound on slots per GRAPH is separate:
+# see kernels.quadrant_descent.PRNG_SLOT_LIMIT.)
+DEVICE_MAX_CANDIDATES = 96 << 20
 
 
 class KPGMParams(NamedTuple):
@@ -114,7 +122,11 @@ def _descend(u: jax.Array, cum: jax.Array) -> Tuple[jax.Array, jax.Array]:
     a = quad >> 1  # source bit-plane, (N, d)
     b = quad & 1  # target bit-plane
     pows = (1 << jnp.arange(d - 1, -1, -1)).astype(jnp.int32)
-    return a @ pows, b @ pows
+    # integer multiply-add, not a matmul: exact on every backend
+    return (
+        jnp.sum(a * pows, axis=1, dtype=jnp.int32),
+        jnp.sum(b * pows, axis=1, dtype=jnp.int32),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("num_edges",))
@@ -251,7 +263,8 @@ def _many_round(
         cum_asks, jnp.arange(num_candidates, dtype=asks.dtype), side="right"
     ).astype(jnp.int32)
     take, counts = dedup.segmented_unique_mask(
-        graph_id, src, dst, cum_asks, targets, node_bits=d
+        graph_id, src, dst, cum_asks, targets, node_bits=d,
+        max_ask=num_candidates,
     )
     return src, dst, take, counts
 
@@ -374,12 +387,22 @@ def edge_prob_matrix(thetas: jax.Array) -> jax.Array:
 
 
 def log_prob_pairs(thetas: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
-    """log P_{src,dst} for 0-based id pairs, evaluated via eq. (6)."""
+    """log P_{src,dst} for 0-based id pairs, evaluated via eq. (6).
+
+    One select per level over the (E,) pairs, so the compiled program holds
+    no (E, d) intermediate — the exact-cell rounds call it on every
+    candidate."""
     d = thetas.shape[0]
-    ks = jnp.arange(d)
-    shift = d - 1 - ks
-    a = (src[:, None] >> shift[None, :]) & 1  # (E, d)
-    b = (dst[:, None] >> shift[None, :]) & 1
     logt = jnp.log(jnp.clip(thetas, 1e-30, 1.0))  # (d, 2, 2)
-    vals = logt[ks[None, :], a, b]
-    return jnp.sum(vals, axis=1)
+    total = jnp.zeros(jnp.shape(src), logt.dtype)
+    for k in range(d):
+        shift = d - 1 - k
+        a = ((src >> shift) & 1) == 1
+        b = ((dst >> shift) & 1) == 1
+        lk = logt[k]
+        total = total + jnp.where(
+            a,
+            jnp.where(b, lk[1, 1], lk[1, 0]),
+            jnp.where(b, lk[0, 1], lk[0, 0]),
+        )
+    return total

@@ -79,33 +79,6 @@ def build_partition(lam: np.ndarray) -> Partition:
 CFG_SENTINEL = np.int32(2**31 - 1)  # larger than any d<=31 config id
 
 
-class PaddedTables(NamedTuple):
-    """Fixed-shape per-block lookup tables for the device quilting pipeline.
-
-    Row c-1 holds D_c's configs ascending (CFG_SENTINEL padding) and the node
-    ids aligned with them (-1 padding); every row has the same width so the
-    whole structure ships to the device as two (B, L) int32 arrays.
-    """
-
-    configs: np.ndarray  # (B, L) int32, rows ascending + sentinel padding
-    nodes: np.ndarray  # (B, L) int32, -1 padding
-    lengths: np.ndarray  # (B,) true row lengths
-
-
-def padded_lookup_tables(part: Partition, min_width: int = 8) -> PaddedTables:
-    width = max([min_width] + [c.size for c in part.sorted_configs])
-    width += (-width) % 8
-    cfg = np.full((part.B, width), CFG_SENTINEL, dtype=np.int32)
-    node = np.full((part.B, width), -1, dtype=np.int32)
-    lengths = np.zeros(part.B, dtype=np.int64)
-    for b in range(part.B):
-        m = part.sorted_configs[b].size
-        cfg[b, :m] = part.sorted_configs[b]
-        node[b, :m] = part.sorted_nodes[b]
-        lengths[b] = m
-    return PaddedTables(configs=cfg, nodes=node, lengths=lengths)
-
-
 def dense_inverse(part: Partition, d: int) -> np.ndarray:
     """(B, 2^d) int32 map config -> node id per block (-1 when absent).
 

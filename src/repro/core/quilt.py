@@ -37,9 +37,9 @@ across a device mesh:
    single-device paths bit-identical and top-up rounds prefix-stable.
 3. **Descent + lookup + dedup** — one fused program per round draws the
    candidates for ALL local block pairs: quadrant descent produces config
-   ids, mapped through the per-block lookup tables on-device (Pallas kernel
-   ``kernels/quadrant_descent.quilt_descent_lookup`` on TPU, jnp dense-gather
-   fallback on CPU) with -1 marking a membership miss, then the sort-based
+   ids (Pallas kernel ``kernels/quadrant_descent.descent_prng`` on TPU, its
+   bit-identical jnp twin elsewhere), an XLA gather maps them to node ids
+   (:func:`gather_nodes`) with -1 marking a membership miss, then the sort-based
    segmented dedup (core/dedup.py) over ``(graph_id << 2d) | src << d | dst``
    packed keys returns a fixed-shape take mask + per-graph unique counts.
 4. **Mesh sharding** — with ``mesh=``, the B^2 graphs are placed along the
@@ -79,9 +79,7 @@ from typing import List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
-from repro.compat import shard_map as _shard_map
 from repro.core import dedup, kpgm, kron, magm, partition
 from repro.dist import chaos
 from repro.kernels import ops
@@ -102,7 +100,7 @@ class QuiltStats(NamedTuple):
 # ---------------------------------------------------------------------------
 
 # dense config->node inverse above this many entries would dominate memory;
-# larger plans fall back to the sorted-table kernel / host path
+# larger plans look nodes up through the O(2^d + n) by-config triple
 DENSE_INV_CAP = 1 << 24
 
 
@@ -110,9 +108,10 @@ class QuiltPlan(NamedTuple):
     """Precomputed device state for quilting one attribute matrix.
 
     Built (and content-cached) by :func:`get_quilt_plan`: the Theorem-2
-    partition, the padded per-block lookup tables (+ optional dense
-    config -> node inverse), the cumulative quadrant probabilities, and the
-    |E| moments — everything :func:`quilt_sample` needs besides the key.
+    partition, the config -> node lookups of the device rounds (the dense
+    inverse and/or the by-config triple, see :func:`device_lookup`), the
+    cumulative quadrant probabilities, and the |E| moments — everything
+    :func:`quilt_sample` needs besides the key.
 
     Examples
     --------
@@ -134,8 +133,6 @@ class QuiltPlan(NamedTuple):
     part: partition.Partition  # host-side partition (top-up + stats)
     thetas: jax.Array  # (d, 2, 2)
     cum: jax.Array  # (d, 4) cumulative quadrant probabilities
-    table_cfg: jax.Array  # (B, L) sorted configs, CFG_SENTINEL padded
-    table_node: jax.Array  # (B, L) node ids, -1 padded
     inv: Optional[jax.Array]  # (B, 2^d) dense inverse or None
     mean_edges: float  # E|E| of one KPGM draw
     std_edges: float  # sqrt(m - v)
@@ -152,7 +149,8 @@ class QuiltPlan(NamedTuple):
     # (node-index) order.  cfg_nodes[cfg_offset[x] + b] is the SAME node as
     # partition.dense_inverse[b, x] in O(2^d + n) memory instead of
     # O(B * 2^d) — the ball-dropping rank lookup for skewed mu, where
-    # B = c_max makes the dense inverse blow past DENSE_INV_CAP
+    # B = c_max makes the dense inverse blow past DENSE_INV_CAP, and the
+    # quilt's own lookup once it does
     cfg_offset: Optional[jax.Array] = None  # (2^d,) int32 exclusive prefix
     cfg_count: Optional[jax.Array] = None  # (2^d,) int32 multiplicities
     cfg_nodes: Optional[jax.Array] = None  # (n,) int32 grouped node ids
@@ -160,6 +158,12 @@ class QuiltPlan(NamedTuple):
     @property
     def num_graphs(self) -> int:
         return self.B * self.B
+
+    @property
+    def exact_budget(self) -> Optional[int]:
+        """Proposals per block-pair graph of the exact-cell round (None
+        when no finite budget exists; see :func:`_exact_budget`)."""
+        return _exact_budget(self.p_max, self.mean_edges)
 
 
 PLAN_STATS = {"partition_builds": 0, "plan_builds": 0, "plan_hits": 0}
@@ -210,7 +214,6 @@ def _partition_state(F: np.ndarray, d: int):
     lam = np.asarray(magm.configs_from_attributes(jnp.asarray(F)))
     part = partition.build_partition(lam)
     PLAN_STATS["partition_builds"] += 1
-    tables = partition.padded_lookup_tables(part) if part.B else None
     inv_np = (
         partition.dense_inverse(part, d)
         if part.B and part.B * (1 << d) <= DENSE_INV_CAP
@@ -226,7 +229,7 @@ def _partition_state(F: np.ndarray, d: int):
         offset[1:] = np.cumsum(count[:-1])
         nodes = np.argsort(lam, kind="stable").astype(np.int32)
         bycfg_np = (offset, count, nodes)
-    return part, tables, inv_np, bycfg_np
+    return part, inv_np, bycfg_np
 
 
 @jax.jit
@@ -248,7 +251,7 @@ def _plan_constants(th_dev: jax.Array):
 
 
 def _assemble_plan(F: np.ndarray, th: np.ndarray, part_state) -> QuiltPlan:
-    part, tables, inv_np, bycfg_np = part_state
+    part, inv_np, bycfg_np = part_state
     n, d = F.shape
     th_dev = jnp.asarray(th)
     cum, m_dev, std_dev, pmax_dev = _plan_constants(th_dev)
@@ -266,8 +269,6 @@ def _assemble_plan(F: np.ndarray, th: np.ndarray, part_state) -> QuiltPlan:
         part=part,
         thetas=th_dev,
         cum=cum,
-        table_cfg=jnp.asarray(tables.configs) if tables else jnp.zeros((0, 8), jnp.int32),
-        table_node=jnp.asarray(tables.nodes) if tables else jnp.zeros((0, 8), jnp.int32),
         inv=jnp.asarray(inv_np) if inv_np is not None else None,
         mean_edges=m,
         std_edges=std,
@@ -387,8 +388,11 @@ def get_quilt_plan(F: np.ndarray, thetas: jax.Array) -> QuiltPlan:
 # that host_topup_rounds stays 0 on the default backend.  mesh_degrades
 # counts dispatch-time device losses recovered by rebuilding the mesh over
 # the survivors; degraded_fallbacks counts max_rounds-exhausted runs that
-# fell through to the host top-up loop (both also warn — degradation is
-# observable, never silent)
+# fell through to the host top-up loop; exact_fallbacks counts runs that
+# wanted the exact-cell round but took the drawn-target rounds; and
+# host_fallbacks counts backend="auto" runs that left the device for the
+# host loop.  Every one of them also warns — degradation is observable,
+# never silent.
 DISPATCH_COUNTERS = {
     "device_rounds": 0,
     "device_topup_rounds": 0,
@@ -396,7 +400,77 @@ DISPATCH_COUNTERS = {
     "mesh_degrades": 0,
     "degraded_fallbacks": 0,
     "exact_fallbacks": 0,
+    "host_fallbacks": 0,
 }
+
+# the counters above that must stay 0 for a run to have stayed on its
+# device path (the chip smoke and the tests read them)
+FALLBACK_COUNTERS = (
+    "host_topup_rounds",
+    "mesh_degrades",
+    "degraded_fallbacks",
+    "exact_fallbacks",
+    "host_fallbacks",
+)
+
+
+def fallback(counters: dict, name: str, message: str) -> None:
+    """Count a departure from the device path and say so."""
+    counters[name] += 1
+    warnings.warn(message, RuntimeWarning, stacklevel=3)
+
+
+def device_lookup(plan: "QuiltPlan"):
+    """The config -> node gather tables of the device rounds: ``(inv,)``,
+    the dense (B, 2^d) inverse, when it was built, else the by-config
+    triple ``(cfg_offset, cfg_count, cfg_nodes)``; None when the plan has
+    neither (no device lookup exists at this size)."""
+    if plan.inv is not None:
+        return (plan.inv,)
+    if plan.cfg_offset is not None:
+        return (plan.cfg_offset, plan.cfg_count, plan.cfg_nodes)
+    return None
+
+
+def gather_nodes(tables, kb, lb, scfg, dcfg, d: int):
+    """Node ids of the (block, config) pairs, -1 where the block does not
+    hold the config — one XLA gather per side.
+
+    With the by-config triple, rank k of config x is x's k-th node in
+    node-index order (a hit iff ``k < c_x``): the Theorem-2 occurrence
+    rank, so both table forms give the same ids bit for bit."""
+    if len(tables) == 1:
+        flat = tables[0].reshape(-1)
+        return flat[(kb << d) | scfg], flat[(lb << d) | dcfg]
+    cfg_offset, cfg_count, cfg_nodes = tables
+
+    def one(rank, cfg):
+        c = cfg_count[cfg]
+        idx = cfg_offset[cfg] + jnp.minimum(rank, jnp.maximum(c - 1, 0))
+        return jnp.where(rank < c, cfg_nodes[idx], jnp.int32(-1))
+
+    return one(kb, scfg), one(lb, dcfg)
+
+
+def graph_major(gids: jax.Array, a_tot: int):
+    """(local graph index, global graph id) of every candidate of a
+    graph-major round of ``a_tot`` slots per graph — broadcasts, not
+    gathers."""
+    gc = gids.shape[0]
+    local = jnp.broadcast_to(
+        jnp.arange(gc, dtype=jnp.int32)[:, None], (gc, a_tot)
+    ).reshape(-1)
+    gid = jnp.broadcast_to(
+        gids.astype(jnp.int32)[:, None], (gc, a_tot)
+    ).reshape(-1)
+    return local, gid
+
+
+def slots_fit(a_tot: int) -> bool:
+    """Whether ``a_tot`` slots per graph fit the counter word
+    ``slot * PRNG_CHANNELS + channel`` in uint32 (kept apart from the
+    device-memory cap ``kpgm.DEVICE_MAX_CANDIDATES``)."""
+    return int(a_tot) <= ops.PRNG_SLOT_LIMIT
 
 
 def _pad_inputs(gtot: int, g_pad: int, targets: np.ndarray):
@@ -436,7 +510,8 @@ def _exact_budget(p_max: Optional[float], mean_edges: float) -> Optional[int]:
     g = math.log1p(-p) / math.log1p(-ratio)
     if not math.isfinite(g) or g > float(kpgm.DEVICE_MAX_CANDIDATES):
         return None
-    return max(int(math.ceil(g)), 1)
+    g = max(int(math.ceil(g)), 1)
+    return g if slots_fit(g) else None
 
 
 def _accept_u01(salt: jax.Array, gid: jax.Array, cell: jax.Array) -> jax.Array:
@@ -561,12 +636,12 @@ def _round_body(
     bit-identical by construction (no per-device state enters the hash).
 
     Returns fixed-shape (scfg, dcfg, snode, dnode, take, counts); call under
-    dedup.call_x64.  ``tables`` is (table_cfg, table_node) for the Pallas
-    kernel path (which derives the SAME variates in-kernel — no HBM uniforms
-    operand) or (inv,) for the jnp dense-gather path (CPU); the two paths
-    are bit-identical by shared integer math.  No collectives: with
-    shard_map, the caller's gather of the outputs is the only cross-device
-    step.
+    dedup.call_x64.  ``use_kernel`` picks the descent: the Pallas kernel
+    (which derives the variates in-kernel — no HBM uniforms operand) or its
+    jnp twin; the two are bit-identical by shared integer math.  Either way
+    ``tables`` (:func:`device_lookup`) maps configs to nodes with an XLA
+    gather.  No collectives: with shard_map, the caller's gather of the
+    outputs is the only cross-device step.
 
     ``exact=True`` is the exact-cell mode (single round, plan-constant
     budget): instead of ranking first-N-distinct cells against a drawn
@@ -580,31 +655,20 @@ def _round_body(
     gc = gids.shape[0]
     a_tot = int(sum(rounds))
     seed = ops.counter_seed(rkey)
-    local = (jnp.arange(gc * a_tot, dtype=jnp.int32) // a_tot).astype(
-        jnp.int32
-    )
-    gid = gids[local]
+    local, gid = graph_major(gids, a_tot)
     if use_kernel:
-        table_cfg, table_node = tables
-        scfg, dcfg, snode, dnode = ops.quilt_prng_descent_lookup_pallas(
-            seed, gids, cum, table_cfg, table_node,
-            a_tot=a_tot, num_blocks=num_blocks,
-        )
+        scfg, dcfg = ops.descent_prng_pallas(seed, gids, cum, a_tot=a_tot)
     else:
-        (inv,) = tables
         slot = jnp.arange(gc * a_tot, dtype=jnp.int32) - local * a_tot
         u = ops.descent_uniforms(seed[0, 0], seed[0, 1], gid, slot, d)
         scfg, dcfg = kpgm._descend(u, cum)
-        # graph ids beyond B^2 are batched samples (repro.api
-        # sample_batch): sample s's block pair g' lives at
-        # gid = s * B^2 + g', so the block decode reduces mod B^2 (a no-op
-        # for the single-sample gid < B^2 case)
-        block = gid % (num_blocks * num_blocks)
-        kb = block // num_blocks
-        lb = block % num_blocks
-        flat = inv.reshape(-1)
-        snode = flat[(kb << d) | scfg]
-        dnode = flat[(lb << d) | dcfg]
+    # graph ids beyond B^2 are batched samples (repro.api sample_batch):
+    # sample s's block pair g' lives at gid = s * B^2 + g', so the block
+    # decode reduces mod B^2 (a no-op for the single-sample gid < B^2 case)
+    block = gid % (num_blocks * num_blocks)
+    kb = block // num_blocks
+    lb = block % num_blocks
+    snode, dnode = gather_nodes(tables, kb, lb, scfg, dcfg, d)
     cum_asks = jnp.arange(1, gc + 1, dtype=jnp.int32) * a_tot
     valid = None
     if exact:
@@ -616,7 +680,8 @@ def _round_body(
             & _exact_cell_valid(rkey, gid, scfg, dcfg, thetas, rounds[0])
         )
     take, counts = dedup.segmented_unique_mask(
-        local, scfg, dcfg, cum_asks, targets, node_bits=d, valid=valid
+        local, scfg, dcfg, cum_asks, targets, node_bits=d, valid=valid,
+        max_ask=a_tot,
     )
     return scfg, dcfg, snode, dnode, take, counts
 
@@ -649,12 +714,12 @@ def _compiled_round(
     if mesh is not None:
         spec = jax.sharding.PartitionSpec(axes)
         rep = jax.sharding.PartitionSpec()
-        body = _shard_map(
+        body = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(rep, spec, spec, rep, rep, (rep,) * num_tables),
             out_specs=(spec,) * 6,
-            check_rep=False,
+            check_vma=False,
         )
     return jax.jit(body)
 
@@ -874,24 +939,33 @@ def quilt_run(
 
     if use_kernel is None:
         use_kernel = not ops.INTERPRET
-    if plan.inv is None and not use_kernel:
-        # no dense inverse (B * 2^d over DENSE_INV_CAP): the sorted-table
-        # kernel path is the only device lookup that exists at this size
-        use_kernel = True
+    lookup = device_lookup(plan)
+    if backend == "device" and lookup is None:
+        raise ValueError(
+            "backend='device' needs a device lookup, and this plan has none "
+            f"(d={plan.d}: 2 * 2^d is over DENSE_INV_CAP={DENSE_INV_CAP})"
+        )
 
     exact = (not targets_given) if exact_cells is None else bool(exact_cells)
     exact = (
         exact
         and not targets_given
         and backend in ("auto", "device")
-        and (plan.inv is not None or use_kernel)
+        and lookup is not None
         and gtot > 0
     )
-    budget = _exact_budget(plan.p_max, plan.mean_edges) if exact else None
+    budget = plan.exact_budget if exact else None
     if exact and (
         budget is None or gtot * budget > kpgm.DEVICE_MAX_CANDIDATES
     ):
-        DISPATCH_COUNTERS["exact_fallbacks"] += 1
+        fallback(
+            DISPATCH_COUNTERS,
+            "exact_fallbacks",
+            f"exact-cell round over the device budget ({gtot} graphs x "
+            f"{budget} proposals > DEVICE_MAX_CANDIDATES="
+            f"{kpgm.DEVICE_MAX_CANDIDATES}, or no finite budget): taking "
+            "the drawn-target rounds instead",
+        )
         exact = False
 
     key, sub = jax.random.split(key)
@@ -929,8 +1003,9 @@ def quilt_run(
     # with spare aggregate memory can force backend="device" instead
     use_device = exact or backend == "device" or (
         backend == "auto"
-        and (plan.inv is not None or use_kernel)
+        and lookup is not None
         and gtot * ask0 <= kpgm.DEVICE_MAX_CANDIDATES
+        and slots_fit(ask0)
     )
     if not use_device:
         if S > 1:
@@ -945,6 +1020,15 @@ def quilt_run(
             raise DeviceBatchUnavailable(
                 "targets override needs the device backend "
                 f"(backend={backend!r}, candidates={gtot * ask0})"
+            )
+        if backend == "auto":
+            fallback(
+                DISPATCH_COUNTERS,
+                "host_fallbacks",
+                f"backend='auto' is sampling on the host: {gtot} graphs x "
+                f"{ask0} slots is over DEVICE_MAX_CANDIDATES="
+                f"{kpgm.DEVICE_MAX_CANDIDATES} or the counter-PRNG slot "
+                "limit, or the plan has no device lookup",
             )
         edges, st = _quilt_sample_host(
             key, plan, max_rounds=max_rounds, oversample=oversample
@@ -964,16 +1048,17 @@ def quilt_run(
 
     if total > 0:
         gids_j, tpad_j = _pad_inputs(gtot, g_pad, targets)
-        tables = (
-            (plan.table_cfg, plan.table_node) if use_kernel else (plan.inv,)
-        )
+        tables = lookup
         rounds: Tuple[int, ...] = ()
         for r in range(1 if exact else max_rounds):
             chaos.maybe_fail("quilt.round")
             ask = budget if exact else dedup.uniform_ask(shortfall, oversample)
             if ask == 0:
                 break
-            if rounds and gtot * (sum(rounds) + ask) > kpgm.DEVICE_MAX_CANDIDATES:
+            if rounds and (
+                gtot * (sum(rounds) + ask) > kpgm.DEVICE_MAX_CANDIDATES
+                or not slots_fit(sum(rounds) + ask)
+            ):
                 # the cumulative stream would outgrow the device budget
                 # (near-saturated targets): let the host fallback finish the
                 # residual instead of OOMing.  Like the backend decision,
@@ -1027,15 +1112,14 @@ def quilt_run(
         if shortfall.max(initial=0) > 0:
             # pathological: max_rounds device rounds still short — fall back
             # to the PR-1 host rejection loop for the residual
-            DISPATCH_COUNTERS["degraded_fallbacks"] += 1
-            warnings.warn(
+            fallback(
+                DISPATCH_COUNTERS,
+                "degraded_fallbacks",
                 f"device rounds exhausted (max_rounds={max_rounds}, "
                 f"{a_tot} slots/graph) with {int(shortfall.sum())} edges "
                 "still short: finishing the residual with the host "
                 "rejection loop (raise max_rounds or oversample to stay "
                 "device-resident)",
-                RuntimeWarning,
-                stacklevel=2,
             )
             flat_taken = (
                 jax.device_get(scfg)[take_h].astype(np.int64) * ncfg
@@ -1431,7 +1515,7 @@ def _heavy_device_state(n, W, sizes, offs, cat, p_hh, p_wh, p_hw) -> dict:
     precomputed per block, and the sampling round needs no probability
     math at all.  All arrays are device-put at build time (the warm path
     ships nothing under ``transfer_guard("disallow")``); ``blk_cumw`` is
-    f64 (placed under ``enable_x64``) because block selection by
+    f64 (placed under ``jax.enable_x64``) because block selection by
     searchsorted over up to ~1e5 blocks needs more than f32's 2^-24 grid.
     """
     R = int(sizes.size)
@@ -1477,7 +1561,7 @@ def _heavy_device_state(n, W, sizes, offs, cat, p_hh, p_wh, p_hw) -> dict:
     cumw = np.cumsum(w) / s_h
     cumw[-1] = 1.0
     pool = np.concatenate([cat, W]).astype(np.int32)
-    with enable_x64():
+    with jax.enable_x64(True):
         state = {
             "pool": jax.device_put(pool),
             "blk_rows": jax.device_put(rows.astype(np.int32)),
@@ -1600,7 +1684,7 @@ def _split_heavy_body(
     targets = jnp.array([budget], dtype=jnp.int64)
     take, _ = dedup.segmented_unique_mask(
         local, src, dst, cum_asks, targets,
-        node_bits=node_bits, valid=accept,
+        node_bits=node_bits, max_ask=budget, valid=accept,
     )
     return src, dst, take
 
@@ -1687,6 +1771,12 @@ def split_run(
                 )
     elif R:
         if rng is None:
+            fallback(
+                DISPATCH_COUNTERS,
+                "host_fallbacks",
+                "split heavy part on the host: its exact proposal budget is "
+                f"over DEVICE_MAX_CANDIDATES={kpgm.DEVICE_MAX_CANDIDATES}",
+            )
             rng = rng_from_key(key)
         sizes, offs, cat = sp.sizes, sp.offs, sp.cat
         # (2) heavy x heavy blocks (including the diagonal): scalar-p ER

@@ -332,7 +332,7 @@ class RetryPolicy(NamedTuple):
         """Deterministic sleep before retry ``attempt`` (0-based)."""
         delay = min(self.base_delay * (2.0**attempt), self.max_delay)
         if self.jitter > 0:
-            u = random.Random((self.seed, attempt)).random()
+            u = random.Random((self.seed << 32) + attempt).random()
             delay *= 1.0 + self.jitter * u
         return delay
 

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def _stochastic_round_int8(x: jax.Array, key: jax.Array):
@@ -86,11 +85,11 @@ def compressed_grad_allreduce(
     keys = tuple(jax.random.split(key, max(len(leaves), 1)))
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def reduce_all(leaf_tuple, key_tuple):
         return tuple(

@@ -2,8 +2,8 @@
 
 These handle shape padding (edge-axis to TILE, attribute-axis to the 128-lane
 MXU width, tile axes to (BM, BN)), parameter packing for the bilinear form,
-and the interpret-mode switch (CPU containers validate with interpret=True;
-on TPU `repro.kernels.ops.INTERPRET` flips to False).
+and the interpret-mode switch (``INTERPRET`` is False exactly when JAX's
+default backend is a TPU).
 """
 
 from __future__ import annotations
@@ -18,7 +18,8 @@ from repro.kernels import bernoulli_tile as _bt
 from repro.kernels import magm_logprob as _ml
 from repro.kernels import quadrant_descent as _qd
 
-# CPU containers (this environment) must interpret; set False on real TPU.
+# Pallas kernels compile natively only on a TPU; every other platform runs
+# them in interpret mode (and the engines default to the jnp twins there).
 INTERPRET = jax.default_backend() != "tpu"
 
 # Opt-in for the hardware-PRNG kernel variant (pltpu.prng_random_bits) on a
@@ -29,6 +30,7 @@ TPU_NATIVE_PRNG = False
 # counter-PRNG derivation helpers, re-exported for the core engines so the
 # jnp fallback paths share the kernels' exact integer math (bit-identity)
 PRNG_CHANNELS = _qd.PRNG_CHANNELS
+PRNG_SLOT_LIMIT = _qd.PRNG_SLOT_LIMIT
 counter_seed = _qd.counter_seed
 counter_hash = _qd.counter_hash
 counter_u01 = _qd.counter_u01
@@ -118,32 +120,28 @@ def quilt_descent_lookup_pallas(
     return scfg[:n], dcfg[:n], snode[:n], dnode[:n]
 
 
-def quilt_prng_descent_lookup_pallas(
+def descent_prng_pallas(
     seed: jax.Array,
     gids: jax.Array,
     cumprobs: jax.Array,
-    table_cfg: jax.Array,
-    table_node: jax.Array,
     *,
     a_tot: int,
-    num_blocks: int,
+    num_blocks: int = 1,
     ranks: bool = False,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Counter-PRNG fused descent + lookup (quilt/balldrop kernel path).
+) -> Tuple[jax.Array, ...]:
+    """Counter-PRNG descent kernel of the quilt/balldrop/KPGM rounds.
 
-    Unlike :func:`quilt_descent_lookup_pallas` there is no per-candidate
-    HBM operand to pad: the kernel derives (graph, slot, uniforms, block
-    pair) from its row index, the (1, 2) seed and the (gc,) graph ids, and
-    the wrapper slices the TILE padding off internally.  Bit-identical to
-    the jnp fallback assembled from :func:`descent_uniforms` /
-    :func:`rank_pair` (the kernel path/jnp path parity test relies on it).
+    The kernel derives (graph, slot, uniforms[, block ranks]) from its grid
+    position, the (1, 2) seed and the (gc,) graph ids, and returns
+    ``(src_cfg, dst_cfg[, kb, lb])`` per candidate; the engines map the
+    configs to nodes with an XLA gather.  Bit-identical to the jnp twin
+    assembled from :func:`descent_uniforms` / :func:`rank_pair` (the kernel
+    path/jnp path parity tests rely on it).
     """
-    return _qd.quilt_prng_descent_lookup(
+    return _qd.descent_prng(
         seed,
         gids,
         cumprobs,
-        table_cfg,
-        table_node,
         a_tot=a_tot,
         num_blocks=num_blocks,
         ranks=ranks,

@@ -17,16 +17,21 @@ blocks.  Arithmetic intensity is ~O(d) flops / 4d bytes per edge — the kernel
 is HBM-bandwidth-bound, which is why the fused formulation (no intermediate
 quad / bit-plane tensors round-tripping to HBM) matters.
 
-The uniforms operand is now OPTIONAL: the ``*_prng`` kernel variants below
-generate their variates in-kernel from a counter-based hash of
-``(round_key, graph, slot, channel)`` (`counter_hash`), removing the
-dominant HBM read entirely.  The hash is plain uint32 arithmetic, so the
-same kernel body lowers on CPU interpret mode AND on TPU, and the jnp
-fallback paths (``core/quilt.py`` / ``core/balldrop.py`` with
-``use_kernel=False``) reproduce it bit-for-bit.  A TPU-native variant using
-``pltpu.prng_seed`` / ``pltpu.prng_random_bits`` sits behind the
-``tpu_native`` flag (no CPU lowering exists for those primitives; see
-docs/API.md for the flag + counter-derivation contract).
+The engines' kernel is :func:`descent_prng`: it needs no uniforms operand,
+generating its variates in-kernel from a counter-based hash of
+``(round_key, graph, slot, channel)`` (`counter_hash`), so the only HBM
+traffic is the int32 config ids it writes.  Its layout is lane-dense: a
+grid step owns ``rows x 128`` consecutive slots of one graph, the graph id
+and seed are scalar-prefetched, and the (d, 4) table sits in SMEM.  The
+hash is plain uint32 arithmetic, so the same kernel body lowers in CPU
+interpret mode AND on TPU, and the jnp twins (``core/quilt.py`` /
+``core/balldrop.py`` with ``use_kernel=False``) reproduce it bit-for-bit.
+The config -> node lookup is not in the kernel: the engines gather through
+the plan's tables in XLA.  A TPU-native variant using ``pltpu.prng_seed`` /
+``pltpu.prng_random_bits`` sits behind the ``tpu_native`` flag (no CPU
+lowering exists for those primitives; see docs/API.md for the flag +
+counter-derivation contract).  ``tests/test_chip_compile.py`` compiles the
+kernels for a v5e.
 """
 
 from __future__ import annotations
@@ -145,7 +150,7 @@ def quilt_descent_lookup(
       cumprobs:   (d, 4) cumulative quadrant probabilities.
       kb, lb:     (N, 1) int32 source/target block ids per candidate.
       table_cfg:  (B, L) int32 per-block configs, each row ascending, padded
-                  with INT32_MAX sentinels (partition.padded_lookup_tables).
+                  with INT32_MAX sentinels (partition.CFG_SENTINEL).
       table_node: (B, L) int32 node ids aligned with table_cfg, padding -1.
 
     Returns (src_cfg, dst_cfg, src_node, dst_node), each (N,) int32 with
@@ -223,10 +228,12 @@ def quadrant_descent(
 
 # Channel slots reserved per candidate: channels 0..d-1 carry the descent
 # uniforms (d <= 31 everywhere: int32 config ids), the LAST TWO channels
-# carry the ball-dropping block ranks.  64 = 2^6 keeps the packed word
-# ``slot * 64 + channel`` inside uint32 for every slot the device budget
-# admits (slot < DEVICE_MAX_CANDIDATES = 2^25, so word < 2^31 + 64).
+# carry the ball-dropping block ranks.  The packed word
+# ``slot * 64 + channel`` must fit in uint32, so a graph may hold at most
+# PRNG_SLOT_LIMIT = 2^26 slots; the engines check it where they size a round
+# (quilt.check_slots) and :func:`descent_prng` re-checks its padded layout.
 PRNG_CHANNELS = 64
+PRNG_SLOT_LIMIT = (1 << 32) // PRNG_CHANNELS
 _RANK0 = PRNG_CHANNELS - 2
 
 # lowbias32-style avalanche multipliers (hash-prospector family) plus the
@@ -276,13 +283,23 @@ def counter_hash(
     return _mix32(x)
 
 
+def _u01(bits: jax.Array) -> jax.Array:
+    """f32 uniform in [0, 1) from the top 24 bits of a uint32 word.
+
+    The 24-bit value goes through int32 on its way to f32: Mosaic has no
+    uint32 -> float32 cast, and below 2^24 both conversions are exact, so
+    the kernels and the jnp twin agree bit for bit."""
+    return (bits >> jnp.uint32(8)).astype(jnp.int32).astype(
+        jnp.float32
+    ) * jnp.float32(2.0**-24)
+
+
 def counter_u01(
     s0: jax.Array, s1: jax.Array, gid: jax.Array, word: jax.Array
 ) -> jax.Array:
     """f32 uniform in [0, 1) from the top 24 bits of :func:`counter_hash`
     (24 bits = full f32 mantissa precision, exact float conversion)."""
-    bits = counter_hash(s0, s1, gid, word) >> jnp.uint32(8)
-    return bits.astype(jnp.float32) * jnp.float32(2.0**-24)
+    return _u01(counter_hash(s0, s1, gid, word))
 
 
 def counter_rank(
@@ -293,9 +310,11 @@ def counter_rank(
     num_blocks: int,
 ) -> jax.Array:
     """int32 rank in [0, num_blocks) from 31 hash bits (modulo bias is
-    <= num_blocks * 2^-31 per bucket — B never exceeds n <= 2^25)."""
-    bits = counter_hash(s0, s1, gid, word) >> jnp.uint32(1)
-    return (bits % jnp.uint32(num_blocks)).astype(jnp.int32)
+    <= num_blocks * 2^-31 per bucket — B never exceeds n <= 2^25).  The
+    31-bit value is non-negative as int32, so the remainder is taken in
+    int32, which both Mosaic and XLA lower."""
+    bits = (counter_hash(s0, s1, gid, word) >> jnp.uint32(1)).astype(jnp.int32)
+    return jax.lax.rem(bits, jnp.int32(num_blocks))
 
 
 def counter_seed(key: jax.Array) -> jax.Array:
@@ -335,53 +354,170 @@ def rank_pair(
     return kb, lb
 
 
-def _descend_body(u, cum, d: int):
-    """Shared descent arithmetic: (TILE, d) uniforms -> (TILE, 1) cfg ids."""
-    quad = (
-        (u >= cum[None, :, 0]).astype(jnp.int32)
-        + (u >= cum[None, :, 1]).astype(jnp.int32)
-        + (u >= cum[None, :, 2]).astype(jnp.int32)
+# Lane-dense layout of the counter-PRNG descent kernel: a grid step owns
+# ``rows x 128`` consecutive slots of ONE graph, so every live value is a
+# full (8k, 128) int32 tile and the graph id is a scalar read.
+LANES = 128
+MAX_ROWS = 32
+
+
+def _rows_for(a_tot: int) -> int:
+    """Sublane rows per grid step: up to MAX_ROWS, rounded to 8."""
+    need = -(-max(int(a_tot), 1) // LANES)
+    return min(MAX_ROWS, need + (-need) % 8)
+
+
+def _descent_kernel(
+    gids_ref, seed_ref, cum_ref, *out_refs, d: int, rows: int,
+    num_blocks: int, ranks: bool, native: bool,
+):
+    """Counter-PRNG quadrant descent for one (graph, slot-tile) grid step.
+
+    ``gids_ref`` and ``seed_ref`` are scalar-prefetched (SMEM), ``cum_ref``
+    is the (d, 4) cumulative quadrant table in SMEM.  Level k's uniform is
+    channel k of the slot's counter word, and the config ids accumulate a
+    bit per level — the same integers the jnp twin gets from
+    :func:`descent_uniforms` + ``kpgm._descend``.  With ``ranks=True`` the
+    two reserved rank channels are emitted too (ball dropping).
+
+    ``native=True`` draws every channel from the TPU's hardware PRNG
+    (``pltpu.prng_random_bits``, seeded per grid step) instead of the
+    counter hash: a deployment-speed option with the same law but NOT the
+    same bits, and no interpret-mode lowering.
+    """
+    g = pl.program_id(0)
+    j = pl.program_id(1)
+    gid = gids_ref[g]
+    s0 = seed_ref[0]
+    s1 = seed_ref[1]
+    shape = (rows, LANES)
+    slot = (
+        j * (rows * LANES)
+        + jax.lax.broadcasted_iota(jnp.int32, shape, 0) * LANES
+        + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     )
-    a = quad >> 1
-    b = quad & 1
-    k = jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)
-    pows = jnp.int32(1) << (jnp.int32(d - 1) - k)
-    scfg = jnp.sum(a * pows, axis=1, keepdims=True, dtype=jnp.int32)
-    dcfg = jnp.sum(b * pows, axis=1, keepdims=True, dtype=jnp.int32)
-    return scfg, dcfg
+    base = slot.astype(jnp.uint32) * jnp.uint32(PRNG_CHANNELS)
+    if native:
+        from jax.experimental.pallas import tpu as pltpu  # TPU-only
+
+        pltpu.prng_seed(s0 + g * pl.num_programs(1) + j, s1 ^ gid)
+
+    def channel_bits(channel: int) -> jax.Array:
+        if native:
+            return jax.lax.bitcast_convert_type(
+                pltpu.prng_random_bits(shape), jnp.uint32
+            )
+        return counter_hash(s0, s1, gid, base + jnp.uint32(channel))
+
+    def rank(channel: int) -> jax.Array:
+        bits = (channel_bits(channel) >> jnp.uint32(1)).astype(jnp.int32)
+        return jax.lax.rem(bits, jnp.int32(num_blocks))
+
+    scfg = jnp.zeros(shape, jnp.int32)
+    dcfg = jnp.zeros(shape, jnp.int32)
+    for k in range(d):
+        u = _u01(channel_bits(k))
+        quad = (
+            (u >= cum_ref[k, 0]).astype(jnp.int32)
+            + (u >= cum_ref[k, 1]).astype(jnp.int32)
+            + (u >= cum_ref[k, 2]).astype(jnp.int32)
+        )
+        scfg = (scfg << 1) | (quad >> 1)
+        dcfg = (dcfg << 1) | (quad & 1)
+    out_refs[0][...] = scfg
+    out_refs[1][...] = dcfg
+    if ranks:
+        out_refs[2][...] = rank(_RANK0)
+        out_refs[3][...] = rank(_RANK0 + 1)
 
 
-def _prng_kernel(seed_ref, cum_ref, src_ref, dst_ref, *, d: int):
-    """Quadrant descent with in-kernel counter-PRNG uniforms: the ONLY
-    HBM inputs are the (1, 2) seed and the (d, 4) table."""
-    cum = cum_ref[...]
-    i = pl.program_id(0)
-    row = i * TILE + jax.lax.broadcasted_iota(jnp.int32, (TILE, 1), 0)
-    k = jax.lax.broadcasted_iota(jnp.uint32, (1, d), 1)
-    word = row.astype(jnp.uint32) * jnp.uint32(PRNG_CHANNELS) + k
-    s = seed_ref[...]
-    u = counter_u01(s[0, 0], s[0, 1], jnp.int32(0), word)
-    src, dst = _descend_body(u, cum, d)
-    src_ref[...] = src
-    dst_ref[...] = dst
+@functools.partial(
+    jax.jit,
+    static_argnames=("a_tot", "num_blocks", "ranks", "interpret", "native"),
+)
+def descent_prng(
+    seed: jax.Array,
+    gids: jax.Array,
+    cumprobs: jax.Array,
+    *,
+    a_tot: int,
+    num_blocks: int = 1,
+    ranks: bool = False,
+    interpret: bool = True,
+    native: bool = False,
+):
+    """Counter-PRNG quadrant descent over ``gids.size * a_tot`` candidates.
+
+    Args:
+      seed:       (1, 2) int32 counter seed words (:func:`counter_seed`).
+      gids:       (gc,) or (gc, 1) int32 GLOBAL graph ids of this shard.
+      cumprobs:   (d, 4) cumulative quadrant probabilities.
+      a_tot:      static slots per graph (cumulative over top-up rounds).
+      num_blocks: B — rank range of the ``ranks=True`` channels.
+
+    Returns ``(src_cfg, dst_cfg)`` — plus ``(kb, lb)`` when ``ranks`` —
+    each (gc * a_tot,) int32 in graph-major order, bit-identical to the jnp
+    twin built from :func:`descent_uniforms` / :func:`rank_pair`.  The only
+    HBM inputs are the seed, the graph ids and the table; the per-block
+    config -> node lookup is an XLA gather in the caller.  Each graph's
+    slots are padded to whole ``rows x 128`` tiles inside the kernel and
+    the padding is sliced off here.
+    """
+    gc = int(gids.shape[0])
+    d = int(cumprobs.shape[0])
+    rows = _rows_for(a_tot)
+    tile = rows * LANES
+    bpg = -(-a_tot // tile)
+    a_pad = bpg * tile
+    if a_pad > PRNG_SLOT_LIMIT:
+        raise ValueError(
+            f"{a_pad} slots per graph overflow the uint32 counter word "
+            f"(limit {PRNG_SLOT_LIMIT})"
+        )
+    nout = 4 if ranks else 2
+    # traced with x64 off: the engines call this under dedup.call_x64, and
+    # Mosaic refuses the 64-bit block indices x64 would give the index maps
+    with jax.enable_x64(False):
+        out = _descent_call(
+            gids.reshape(gc).astype(jnp.int32),
+            seed.reshape(2).astype(jnp.int32),
+            cumprobs.astype(jnp.float32),
+            gc=gc, d=d, rows=rows, bpg=bpg, nout=nout,
+            num_blocks=num_blocks, ranks=ranks, interpret=interpret,
+            native=native,
+        )
+    return tuple(o.reshape(gc, a_pad)[:, :a_tot].reshape(-1) for o in out)
 
 
-def _prng_native_kernel(seed_ref, cum_ref, src_ref, dst_ref, *, d: int):
-    """TPU-native variant: hardware PRNG via ``pltpu.prng_random_bits``
-    seeded per grid step.  No CPU interpret lowering exists — gated behind
-    ``tpu_native=True`` in the wrappers.  NOT bit-compatible with the
-    counter hash (a deployment-speed configuration, statistically
-    equivalent; the 3-sigma suite is the contract either way)."""
-    from jax.experimental.pallas import tpu as pltpu  # lazy: TPU-only
+def _descent_call(
+    gids, seed, cumprobs, *, gc, d, rows, bpg, nout, num_blocks, ranks,
+    interpret, native,
+):
+    from jax.experimental.pallas import tpu as pltpu
 
-    cum = cum_ref[...]
-    s = seed_ref[...]
-    pltpu.prng_seed(s[0, 0] + pl.program_id(0), s[0, 1])
-    bits = pltpu.prng_random_bits((TILE, d)).astype(jnp.uint32)
-    u = (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0**-24)
-    src, dst = _descend_body(u, cum, d)
-    src_ref[...] = src
-    dst_ref[...] = dst
+    a_pad = bpg * rows * LANES
+    return pl.pallas_call(
+        functools.partial(
+            _descent_kernel, d=d, rows=rows, num_blocks=num_blocks,
+            ranks=ranks, native=native,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(gc, bpg),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
+            out_specs=[
+                pl.BlockSpec(
+                    (rows, LANES), lambda g, j, *_: (g * bpg + j, 0)
+                )
+                for _ in range(nout)
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((gc * a_pad // LANES, LANES), jnp.int32)
+            for _ in range(nout)
+        ],
+        interpret=interpret,
+    )(gids, seed, cumprobs)
 
 
 @functools.partial(
@@ -398,7 +534,8 @@ def quadrant_descent_prng(
     """Counter-PRNG quadrant descent: (1, 2) seed words + (d, 4) cumulative
     probs -> (src, dst) int32 ids for ``num_slots`` candidates (a multiple
     of TILE; ops.py pads).  Candidate ``s`` draws its level-``k`` uniform
-    from ``counter_u01(seed, gid=0, s * PRNG_CHANNELS + k)``."""
+    from ``counter_u01(seed, gid=0, s * PRNG_CHANNELS + k)`` — the single
+    graph 0 of :func:`descent_prng`."""
     if num_slots % TILE:
         raise ValueError(f"N={num_slots} must be a multiple of TILE={TILE}")
     if tpu_native and interpret:
@@ -407,179 +544,7 @@ def quadrant_descent_prng(
             "interpret lowering — run on a real TPU backend or use the "
             "portable counter-hash kernel (tpu_native=False)"
         )
-    d = cumprobs.shape[0]
-    body = _prng_native_kernel if tpu_native else _prng_kernel
-    grid = (num_slots // TILE,)
-    src, dst = pl.pallas_call(
-        functools.partial(body, d=d),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-            pl.BlockSpec((d, 4), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((TILE, 1), lambda i: (i, 0)),
-            pl.BlockSpec((TILE, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((num_slots, 1), jnp.int32),
-            jax.ShapeDtypeStruct((num_slots, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(seed, cumprobs)
-    return src[:, 0], dst[:, 0]
-
-
-def _prng_quilt_kernel(
-    seed_ref,
-    gids_ref,
-    cum_ref,
-    tcfg_ref,
-    tnode_ref,
-    scfg_ref,
-    dcfg_ref,
-    snode_ref,
-    dnode_ref,
-    *,
-    d: int,
-    table_width: int,
-    steps: int,
-    a_tot: int,
-    num_blocks: int,
-    ranks: bool,
-):
-    """Fused counter-PRNG descent + per-block sorted-config lookup.
-
-    Everything the HBM-uniform ``_quilt_kernel`` read per candidate —
-    (TILE, d) uniforms plus (TILE, 1) kb/lb arrays — is derived in-kernel:
-    the grid step reconstructs each row's (graph, slot) from its global row
-    index, hashes the counter for the descent uniforms, and decodes the
-    block pair either from the graph id (quilting: gid mod B^2) or from the
-    two reserved rank channels (``ranks=True``, ball dropping).  HBM inputs
-    shrink to the seed, the per-shard graph ids, and the plan constants.
-    """
-    cum = cum_ref[...]
-    s = seed_ref[...]
-    s0, s1 = s[0, 0], s[0, 1]
-    gc = gids_ref.shape[0]
-    i = pl.program_id(0)
-    row = i * TILE + jax.lax.broadcasted_iota(jnp.int32, (TILE, 1), 0)
-    # rows past gc * a_tot (TILE padding) clamp to the last graph; the
-    # wrapper slices them off
-    local = jnp.minimum(row // jnp.int32(a_tot), jnp.int32(gc - 1))
-    slot = row - local * jnp.int32(a_tot)
-    flat_g = gids_ref[...].reshape(-1)
-    gid = flat_g[local]  # (TILE, 1) global graph ids
-    k = jax.lax.broadcasted_iota(jnp.uint32, (1, d), 1)
-    base = slot.astype(jnp.uint32) * jnp.uint32(PRNG_CHANNELS)
-    u = counter_u01(s0, s1, gid, base + k)
-    scfg, dcfg = _descend_body(u, cum, d)
-
-    if ranks:
-        kb = counter_rank(s0, s1, gid, base + jnp.uint32(_RANK0), num_blocks)
-        lb = counter_rank(
-            s0, s1, gid, base + jnp.uint32(_RANK0 + 1), num_blocks
-        )
-    else:
-        blk = gid % jnp.int32(num_blocks * num_blocks)
-        kb = blk // jnp.int32(num_blocks)
-        lb = blk - kb * jnp.int32(num_blocks)
-
-    flat_cfg = tcfg_ref[...].reshape(-1)  # (B * L,)
-    flat_node = tnode_ref[...].reshape(-1)
-    length = jnp.int32(table_width)
-
-    def lower_bound(row_, target):
-        lo = jnp.zeros_like(target)
-        hi = jnp.full_like(target, length)
-        for _ in range(steps):
-            mid = (lo + hi) >> 1
-            probe = flat_cfg[row_ * length + jnp.minimum(mid, length - 1)]
-            active = lo < hi
-            go_right = active & (probe < target)
-            lo = jnp.where(go_right, mid + 1, lo)
-            hi = jnp.where(active & ~go_right, mid, hi)
-        pos = jnp.minimum(lo, length - 1)
-        hit = flat_cfg[row_ * length + pos] == target
-        return jnp.where(hit, flat_node[row_ * length + pos], -1)
-
-    snode_ref[...] = lower_bound(kb, scfg)
-    dnode_ref[...] = lower_bound(lb, dcfg)
-    scfg_ref[...] = scfg
-    dcfg_ref[...] = dcfg
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("a_tot", "num_blocks", "ranks", "interpret"),
-)
-def quilt_prng_descent_lookup(
-    seed: jax.Array,
-    gids: jax.Array,
-    cumprobs: jax.Array,
-    table_cfg: jax.Array,
-    table_node: jax.Array,
-    *,
-    a_tot: int,
-    num_blocks: int,
-    ranks: bool = False,
-    interpret: bool = True,
-):
-    """Counter-PRNG fused descent + lookup over ``gids.size * a_tot`` rows.
-
-    Args:
-      seed:       (1, 2) int32 counter seed words (:func:`counter_seed`).
-      gids:       (gc,) or (gc, 1) int32 GLOBAL graph ids of this shard.
-      cumprobs:   (d, 4) cumulative quadrant probabilities.
-      table_cfg:  (B, L) sorted per-block configs (sentinel-padded).
-      table_node: (B, L) aligned node ids (padding -1).
-      a_tot:      static slots per graph (cumulative over top-up rounds).
-      num_blocks: B — block-pair decode modulus (quilting) or rank range
-                  (``ranks=True``, ball dropping).
-
-    Returns (src_cfg, dst_cfg, src_node, dst_node), each (gc * a_tot,)
-    int32, bit-identical to the jnp fallback built from
-    :func:`descent_uniforms` / :func:`rank_pair`.
-    """
-    gc = int(gids.shape[0])
-    n = gc * a_tot
-    n_pad = n + (-n) % TILE
-    bsz, width = table_cfg.shape
-    steps = max(width - 1, 1).bit_length() + 1
-    d = cumprobs.shape[0]
-    grid = (max(n_pad // TILE, 1),)
-    n_pad = grid[0] * TILE
-    out = pl.pallas_call(
-        functools.partial(
-            _prng_quilt_kernel,
-            d=d,
-            table_width=width,
-            steps=steps,
-            a_tot=a_tot,
-            num_blocks=num_blocks,
-            ranks=ranks,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-            pl.BlockSpec((gc, 1), lambda i: (0, 0)),
-            pl.BlockSpec((d, 4), lambda i: (0, 0)),
-            pl.BlockSpec((bsz, width), lambda i: (0, 0)),
-            pl.BlockSpec((bsz, width), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((TILE, 1), lambda i: (i, 0)) for _ in range(4)
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32) for _ in range(4)
-        ],
-        interpret=interpret,
-    )(
-        seed,
-        gids.reshape(gc, 1).astype(jnp.int32),
-        cumprobs,
-        table_cfg,
-        table_node,
+    return descent_prng(
+        seed, jnp.zeros((1,), jnp.int32), cumprobs,
+        a_tot=num_slots, interpret=interpret, native=tpu_native,
     )
-    scfg, dcfg, snode, dnode = out
-    return scfg[:n, 0], dcfg[:n, 0], snode[:n, 0], dnode[:n, 0]

@@ -389,7 +389,7 @@ def serve_graphs(args) -> None:
         f"B={sampler.plan.B} mesh={sampler.mesh}"
     )
 
-    total = empty = 0
+    total = empty = failed = 0
     with GraphServer(
         sampler,
         max_queue=args.max_queue,
@@ -400,6 +400,7 @@ def serve_graphs(args) -> None:
         for r, fut in enumerate(futures):
             resp = fut.result()
             if not resp.ok:
+                failed += 1
                 print(
                     f"[serve] request {r}: {resp.status} ({resp.code}) "
                     f"{resp.message}"
@@ -423,6 +424,13 @@ def serve_graphs(args) -> None:
                     f"waited {resp.wait_s:.3f}s)"
                 )
         stats = dict(server.stats)
+    if failed:
+        # a served run that dropped requests did not pass, whatever the
+        # server survived
+        raise SystemExit(
+            f"[serve] FAILED: {failed} of {args.requests} requests not ok "
+            f"(stats={stats})"
+        )
     if total == 0:
         print(f"[serve] WARNING: all {args.requests} requests were empty")
     print(
@@ -504,6 +512,9 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.magm:
         serve_graphs(args)
     else:

@@ -193,6 +193,8 @@ def test_four_virtual_devices_session_matches(tmp_path):
         """
     )
     env = dict(os.environ)
+    # the child runs on CPU devices: the parent may hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.path.abspath(src)
     env["XLA_FLAGS"] = (
@@ -455,6 +457,26 @@ def test_kpgm_engine_host_fallback_reports_no_fake_target(monkeypatch):
     assert np.unique(flat).size == flat.size
 
 
+def test_auto_backend_host_branch_is_counted_and_warned(monkeypatch):
+    """Past the device budget, backend="auto" may still finish on the host,
+    but never silently: the exact-cell downgrade and the host loop each
+    bump their DISPATCH_COUNTERS entry and raise a RuntimeWarning."""
+    params, F = _attrs(64, 6, seed=1)
+    sampler = MAGMSampler(SamplerConfig(params=params, F=F))
+    monkeypatch.setattr(kpgm, "DEVICE_MAX_CANDIDATES", 8)
+    before = dict(quilt.DISPATCH_COUNTERS)
+    with pytest.warns(RuntimeWarning) as rec:
+        gs = sampler.sample(jax.random.PRNGKey(4))
+    msgs = [str(w.message) for w in rec]
+    assert any("exact-cell round over the device budget" in m for m in msgs)
+    assert any("sampling on the host" in m for m in msgs)
+    c = quilt.DISPATCH_COUNTERS
+    assert c["exact_fallbacks"] == before["exact_fallbacks"] + 1
+    assert c["host_fallbacks"] == before["host_fallbacks"] + 1
+    assert c["device_rounds"] == before["device_rounds"]
+    assert gs.num_edges > 0
+
+
 def test_host_backend_honors_rejection_knobs():
     """SamplerConfig.max_rounds/oversample reach the host reference path."""
     params, F = _attrs(64, 6, seed=1)
@@ -585,6 +607,8 @@ def test_distributed_example_smoke_four_devices():
         os.path.join(here, "..", "examples", "distributed_sampling.py")
     )
     env = dict(os.environ)
+    # the child runs on CPU devices: the parent may hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.abspath(os.path.join(here, "..", "src"))
     env.pop("XLA_FLAGS", None)  # the example forces 4 virtual devices itself
     proc = subprocess.run(

@@ -303,8 +303,9 @@ def test_supervisor_feeds_straggler_monitor(tmp_path):
     mon.on_straggler(lambda step, secs, median: flagged.append(step))
 
     def step_fn(params, opt_state, batch):
-        # a steady 2ms baseline so scheduler noise can't fake a straggler
-        time.sleep(0.1 if batch == 8 else 0.002)
+        # a steady 20ms baseline: a loaded host can stretch a 2ms sleep
+        # past 3x the median, but not a 20ms one
+        time.sleep(0.5 if batch == 8 else 0.02)
         return params, opt_state, {"loss": 0.0}
 
     sup = fault.TrainSupervisor(

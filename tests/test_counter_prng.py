@@ -127,11 +127,9 @@ def test_prng_kernel_matches_jnp_twin(d):
 
 @pytest.mark.parametrize("ranks", [False, True])
 def test_fused_prng_kernel_matches_jnp_twin(ranks):
-    """quilt_prng_descent_lookup == the jnp assembly of descent_uniforms /
-    rank_pair + descent + table lookup, all four outputs bit-exact."""
-    from test_kernels import _random_tables
-
-    d, bsz, width = 6, 5, 16
+    """descent_prng over several graphs == the jnp assembly of
+    descent_uniforms / rank_pair + descent, every output bit-exact."""
+    d, bsz = 6, 5
     a_tot, gc = 700, 3
     rng = np.random.default_rng(42)
     thetas = _thetas(d)
@@ -139,22 +137,21 @@ def test_fused_prng_kernel_matches_jnp_twin(ranks):
     gids = jnp.asarray(
         rng.choice(bsz * bsz, size=gc, replace=False).astype(np.int32)
     )
-    tcfg, tnode = _random_tables(rng, bsz, width, d)
-    got = ops.quilt_prng_descent_lookup_pallas(
-        seed, gids, _cum(thetas), tcfg, tnode,
-        a_tot=a_tot, num_blocks=bsz, ranks=ranks,
+    got = ops.descent_prng_pallas(
+        seed, gids, _cum(thetas), a_tot=a_tot, num_blocks=bsz, ranks=ranks,
     )
     n = gc * a_tot
     local = jnp.arange(n, dtype=jnp.int32) // a_tot
     gid = gids[local]
     slot = jnp.arange(n, dtype=jnp.int32) - local * a_tot
     u = ops.descent_uniforms(seed[0, 0], seed[0, 1], gid, slot, d)
+    want = ref.quadrant_descent_ref(u, _cum(thetas))
+    names = ("scfg", "dcfg")
     if ranks:
-        kb, lb = ops.rank_pair(seed[0, 0], seed[0, 1], gid, slot, bsz)
-    else:
-        kb, lb = gid // bsz, gid % bsz
-    want = ref.quilt_descent_lookup_ref(u, _cum(thetas), kb, lb, tcfg, tnode)
-    for g, w, name in zip(got, want, ("scfg", "dcfg", "snode", "dnode")):
+        want = want + ops.rank_pair(seed[0, 0], seed[0, 1], gid, slot, bsz)
+        names = names + ("kb", "lb")
+    assert len(got) == len(want)
+    for g, w, name in zip(got, want, names):
         assert g.shape == (n,)
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=name)
 

@@ -71,6 +71,40 @@ def test_multikey_fallback_matches_packed():
         np.testing.assert_array_equal(counts, cref)
 
 
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("node_bits", [6, 25])
+def test_within_graph_positions_match_global_arrival(seed, node_bits):
+    """The packed key holds each candidate's position within its graph
+    (bounded by ``max_ask``), not its global arrival index: same take mask
+    and counts as the host reference.  At node_bits=25 a global index would
+    need the 4-operand fallback while the within-graph key fits one int64."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    src, dst, asks, targets = _random_case(rng, 12, 5, 300, dup_heavy=True)
+    max_ask = int(asks.max())
+    cum = np.cumsum(asks)
+    gid = np.searchsorted(cum, np.arange(int(cum[-1])), side="right")
+    _, _, global_fits = dedup._packed_bits(node_bits, asks.size, int(cum[-1]))
+    _, _, fits = dedup._packed_bits(node_bits, asks.size, max_ask)
+    assert fits and global_fits == (node_bits == 6)
+
+    @jax.jit
+    def run(g, s, d, c, t):
+        return dedup.segmented_unique_mask(
+            g, s, d, c, t, node_bits=node_bits, max_ask=max_ask
+        )
+
+    take, counts = dedup.call_x64(
+        run, jnp.asarray(gid, jnp.int32), jnp.asarray(src), jnp.asarray(dst),
+        jnp.asarray(cum, jnp.int32), jnp.asarray(targets, jnp.int32),
+    )
+    tref, cref = dedup.host_unique_reference(src, dst, asks, targets)
+    np.testing.assert_array_equal(np.asarray(take), tref)
+    np.testing.assert_array_equal(np.asarray(counts), cref)
+
+
 def test_all_duplicates_keep_one():
     asks = np.array([100, 50])
     src = np.concatenate([np.full(100, 3), np.full(50, 1)]).astype(np.int32)
@@ -239,6 +273,7 @@ def test_valid_mask_excludes_rejected_candidates():
             jnp.asarray(cum),
             jnp.asarray(targets),
             node_bits=4,
+            max_ask=int(asks.max()),
             valid=valid_arg,
         )
         return np.asarray(take), np.asarray(counts)

@@ -100,6 +100,8 @@ def test_four_virtual_devices_match_single_device(tmp_path):
         """
     )
     env = dict(os.environ)
+    # the child runs on CPU devices: the parent may hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.path.abspath(src)
     env["XLA_FLAGS"] = (

@@ -242,6 +242,8 @@ def test_four_device_loss_mid_run_bit_identical(tmp_path):
         """
     )
     env = dict(os.environ)
+    # the child runs on CPU devices: the parent may hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.path.abspath(src)
     env["XLA_FLAGS"] = (

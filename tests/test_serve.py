@@ -154,3 +154,25 @@ def test_validate_chunk_rejects_malformed():
         _validate_chunk(np.zeros((3, 2), np.float32), 10)
     with pytest.raises(AssertionError, match="outside"):
         _validate_chunk(np.full((3, 2), 99, np.int64), 10)
+
+
+def test_serve_graphs_exits_nonzero_on_failed_request(monkeypatch, capsys):
+    """The graph-serving launcher fails its process when any request is
+    not ``ok`` — a compile error must not pass as a served run."""
+    import argparse
+
+    from repro.launch import serve
+
+    def broken(self, *a, **kw):
+        raise RuntimeError("device program failed")
+
+    monkeypatch.setattr(MAGMSampler, "sample_stream", broken)
+    args = argparse.Namespace(
+        graph_d=4, seed=0, mesh=False, max_queue=8, deadline_s=None,
+        chunk_edges=64, requests=2,
+    )
+    with pytest.raises(SystemExit) as exc:
+        serve.serve_graphs(args)
+    assert exc.value.code not in (0, None)
+    assert "2 of 2 requests not ok" in str(exc.value.code)
+    assert "error (500)" in capsys.readouterr().out
