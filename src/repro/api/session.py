@@ -33,6 +33,7 @@ from typing import Iterator, List, Optional
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.api.config import SamplerConfig
 from repro.api.result import GraphSample, KPGMStats
 from repro.core import dedup, kpgm, magm, quilt
@@ -270,6 +271,7 @@ class MAGMSampler(_Session):
             mesh=self.mesh,
         )
 
+    @tracing.traced("sampler.sample", is_root=True)
     def sample(self, key: Optional[jax.Array] = None) -> GraphSample:
         """Draw one MAGM graph; bit-identical to the legacy free functions
         for the same key.  ``key=None`` consumes the session key stream."""
@@ -335,12 +337,13 @@ class MAGMSampler(_Session):
         via :meth:`resume_stream` — on any mesh (see repro.api.stream).
         """
         key = self._next_key() if key is None else key
-        if checkpoint_dir is None:
-            yield from self._stream_raw(key, chunk_edges)
-        else:
-            yield from self._checkpointed_stream(
-                key, chunk_edges, checkpoint_dir
-            )
+        with tracing.root("sampler.stream"):
+            if checkpoint_dir is None:
+                yield from self._stream_raw(key, chunk_edges)
+            else:
+                yield from self._checkpointed_stream(
+                    key, chunk_edges, checkpoint_dir
+                )
 
     # -- batching ------------------------------------------------------
 
@@ -497,6 +500,7 @@ class KPGMSampler(_Session):
             f"KPGM sampling on the host loop: {why}",
         )
 
+    @tracing.traced("sampler.sample", is_root=True)
     def sample(
         self,
         key: Optional[jax.Array] = None,
@@ -554,12 +558,13 @@ class KPGMSampler(_Session):
         ``checkpoint_dir=`` / :meth:`resume_stream` resume contract —
         including the ``num_edges`` override — is shared)."""
         key = self._next_key() if key is None else key
-        if checkpoint_dir is None:
-            yield from self._stream_raw(key, chunk_edges, num_edges)
-        else:
-            yield from self._checkpointed_stream(
-                key, chunk_edges, checkpoint_dir, num_edges=num_edges
-            )
+        with tracing.root("sampler.stream"):
+            if checkpoint_dir is None:
+                yield from self._stream_raw(key, chunk_edges, num_edges)
+            else:
+                yield from self._checkpointed_stream(
+                    key, chunk_edges, checkpoint_dir, num_edges=num_edges
+                )
 
     def sample_batch(
         self, num_graphs: int, key: Optional[jax.Array] = None

@@ -45,7 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import dedup, kpgm, kron, partition, quilt
+from repro import tracing
+from repro.core import dedup, kpgm, kron, partition, quilt, transfer
 from repro.dist import chaos
 from repro.kernels import ops
 
@@ -54,15 +55,18 @@ __all__ = ["balldrop_run", "DISPATCH_COUNTERS"]
 # fused dispatches of the ball-dropping rounds (analogous to
 # quilt.DISPATCH_COUNTERS; kept separate so the quilt's O(max_rounds)
 # dispatch-count tests are unaffected by balldrop runs)
-DISPATCH_COUNTERS = {
-    "device_rounds": 0,
-    "device_topup_rounds": 0,
-    "host_topup_rounds": 0,
-    "mesh_degrades": 0,
-    "degraded_fallbacks": 0,
-    "exact_fallbacks": 0,
-    "host_fallbacks": 0,
-}
+DISPATCH_COUNTERS = tracing.register(
+    "balldrop.dispatch",
+    {
+        "device_rounds": 0,
+        "device_topup_rounds": 0,
+        "host_topup_rounds": 0,
+        "mesh_degrades": 0,
+        "degraded_fallbacks": 0,
+        "exact_fallbacks": 0,
+        "host_fallbacks": 0,
+    },
+)
 
 
 def _bd_round_body(
@@ -370,7 +374,7 @@ def balldrop_run(
         targets = np.full(S, budget, dtype=np.int64)
     elif targets is None:
         draws = (
-            jax.device_get(jax.random.normal(sub, (S,))) * plan.bd_std
+            transfer.to_host(jax.random.normal(sub, (S,))) * plan.bd_std
             + plan.bd_mean
         )
         targets = np.clip(np.round(draws), 0, n * n).astype(np.int64)
@@ -458,41 +462,49 @@ def balldrop_run(
                 # like quilt_run's guard)
                 break
             rounds = rounds + (ask,)
-            while True:
-                try:
-                    chaos.maybe_fail("quilt.dispatch")
-                    fn = _compiled_bd_round(
-                        mesh, axes, rounds, plan.B, nb, use_kernel,
-                        len(tables), exact,
-                    )
-                    outs = dedup.call_x64(
-                        fn, rkey, gids_j, tpad_j, plan.cum, plan.thetas,
-                        tables,
-                    )
-                    break
-                except chaos.DeviceLoss as exc:
-                    # same degrade-and-rerun recovery as quilt_run: the
-                    # per-sample streams are layout-invariant too
-                    mesh, axes, s_pad = quilt._degrade_layout(
-                        mesh, exc, S, DISPATCH_COUNTERS
-                    )
-                    gids_j, tpad_j = quilt._pad_inputs(S, s_pad, targets)
+            with tracing.span(
+                "quilt.round", round=r, ask=ask, slots=S * sum(rounds)
+            ):
+                while True:
+                    try:
+                        chaos.maybe_fail("quilt.dispatch")
+                        fn = _compiled_bd_round(
+                            mesh, axes, rounds, plan.B, nb, use_kernel,
+                            len(tables), exact,
+                        )
+                        outs = dedup.call_x64(
+                            fn, rkey, gids_j, tpad_j, plan.cum, plan.thetas,
+                            tables,
+                        )
+                        break
+                    except chaos.DeviceLoss as exc:
+                        # same degrade-and-rerun recovery as quilt_run: the
+                        # per-sample streams are layout-invariant too
+                        mesh, axes, s_pad = quilt._degrade_layout(
+                            mesh, exc, S, DISPATCH_COUNTERS
+                        )
+                        gids_j, tpad_j = quilt._pad_inputs(S, s_pad, targets)
             DISPATCH_COUNTERS[
                 "device_rounds" if r == 0 else "device_topup_rounds"
             ] += 1
-            counts = jax.device_get(outs[3]).astype(np.int64)[:S]
+            with tracing.span("quilt.round_wait", round=r):
+                outs[3].block_until_ready()
+            counts = transfer.to_host(outs[3]).astype(np.int64)[:S]
             shortfall = np.zeros_like(targets) if exact else targets - counts
             if shortfall.max(initial=0) <= 0:
                 break
         a_tot = sum(rounds)
+        transfer.ENGINE_COUNTERS["candidate_slots"] += S * a_tot
 
     keep = None
     snode = dnode = None
+    fetched = False
     if outs is not None:
         snode, dnode, take, _ = outs
         # the dedup's valid mask already excludes lookup misses, so taken
         # rows are accepted balls: keep == take (and counts == keep sums)
-        keep = jax.device_get(take)
+        with tracing.span("quilt.mask"):
+            keep = transfer.to_host(take)
         if shortfall.max(initial=0) > 0:
             quilt.fallback(
                 DISPATCH_COUNTERS,
@@ -504,10 +516,13 @@ def balldrop_run(
                 "stay device-resident)",
             )
             flat_taken = (
-                jax.device_get(snode)[keep].astype(np.int64) * n
-                + jax.device_get(dnode)[keep].astype(np.int64)
+                transfer.to_host(snode)[keep].astype(np.int64) * n
+                + transfer.to_host(dnode)[keep].astype(np.int64)
             )
-            full_counts = jax.device_get(outs[3]).astype(np.int64)
+            fetched = True
+            full_counts = transfer.to_host(outs[3], moves=False).astype(
+                np.int64
+            )
             seen_pairs = list(
                 np.split(flat_taken, np.cumsum(full_counts)[:-1])
             )[:S]
@@ -520,5 +535,5 @@ def balldrop_run(
         targets = counts.copy()
     return quilt.QuiltRun(
         plan, S, targets, counts, snode, dnode, keep, a_tot, tuple(tail),
-        None, None, sampler="balldrop",
+        None, None, sampler="balldrop", nodes_fetched=fetched,
     )

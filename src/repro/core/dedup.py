@@ -46,6 +46,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
+from repro.core.transfer import ENGINE_COUNTERS, to_host
+
 __all__ = [
     "bucket_size",
     "plan_asks",
@@ -58,7 +61,6 @@ __all__ = [
     "call_x64",
     "host_unique_reference",
 ]
-
 
 def bucket_size(x: int, tile: int = 1) -> int:
     """Round ``x`` up to the geometric grid {8..15} * 2^k (ratio <= 1.125),
@@ -174,17 +176,22 @@ def rechunk_edges(pieces, chunk_edges: int):
     buf: list = []
     have = 0
     for piece in pieces:
-        p = np.asarray(piece, dtype=np.int64).reshape(-1, 2)
+        with tracing.span("stream.rechunk"):
+            p = np.asarray(piece, dtype=np.int64).reshape(-1, 2)
         while p.shape[0]:
             take = min(chunk_edges - have, p.shape[0])
             buf.append(p[:take])
             have += take
             p = p[take:]
             if have == chunk_edges:
-                yield np.concatenate(buf, axis=0)
+                with tracing.span("stream.rechunk"):
+                    chunk = np.concatenate(buf, axis=0)
+                yield chunk
                 buf, have = [], 0
     if have:
-        yield np.concatenate(buf, axis=0)
+        with tracing.span("stream.rechunk"):
+            chunk = np.concatenate(buf, axis=0)
+        yield chunk
 
 
 def iter_edge_chunks(
@@ -209,12 +216,16 @@ def iter_edge_chunks(
             _row_blocks(src), _row_blocks(dst)
         ):
             for off in range(0, s_blk.shape[0], window):
-                k = keep[base + off : base + min(off + window, s_blk.shape[0])]
-                if not k.any():
-                    continue
-                s = jax.device_get(s_blk[off : off + window])[k]
-                d = jax.device_get(d_blk[off : off + window])[k]
-                yield np.stack([s, d], axis=1)
+                with tracing.span("stream.window"):
+                    hi = min(off + window, s_blk.shape[0])
+                    k = keep[base + off : base + hi]
+                    if not k.any():
+                        continue
+                    s = to_host(s_blk[off : off + window])[k]
+                    d = to_host(d_blk[off : off + window])[k]
+                    piece = np.stack([s, d], axis=1)
+                    ENGINE_COUNTERS["kept_edges"] += piece.shape[0]
+                yield piece
         for t in tail:
             yield t
 

@@ -80,7 +80,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import dedup, kpgm, kron, magm, partition
+from repro import tracing
+from repro.core import dedup, kpgm, kron, magm, partition, transfer
 from repro.dist import chaos
 from repro.kernels import ops
 
@@ -166,7 +167,9 @@ class QuiltPlan(NamedTuple):
         return _exact_budget(self.p_max, self.mean_edges)
 
 
-PLAN_STATS = {"partition_builds": 0, "plan_builds": 0, "plan_hits": 0}
+PLAN_STATS = tracing.register(
+    "quilt.plan", {"partition_builds": 0, "plan_builds": 0, "plan_hits": 0}
+)
 _PART_CACHE: "OrderedDict" = OrderedDict()
 _PLAN_CACHE: "OrderedDict" = OrderedDict()
 _KPGM_PLAN_CACHE: "OrderedDict" = OrderedDict()
@@ -393,15 +396,21 @@ def get_quilt_plan(F: np.ndarray, thetas: jax.Array) -> QuiltPlan:
 # host_fallbacks counts backend="auto" runs that left the device for the
 # host loop.  Every one of them also warns — degradation is observable,
 # never silent.
-DISPATCH_COUNTERS = {
-    "device_rounds": 0,
-    "device_topup_rounds": 0,
-    "host_topup_rounds": 0,
-    "mesh_degrades": 0,
-    "degraded_fallbacks": 0,
-    "exact_fallbacks": 0,
-    "host_fallbacks": 0,
-}
+DISPATCH_COUNTERS = tracing.register(
+    "quilt.dispatch",
+    {
+        "device_rounds": 0,
+        "device_topup_rounds": 0,
+        "host_topup_rounds": 0,
+        "mesh_degrades": 0,
+        "degraded_fallbacks": 0,
+        "exact_fallbacks": 0,
+        "host_fallbacks": 0,
+    },
+)
+
+# candidate slots, kept edges and device-to-host bytes (core/transfer.py)
+ENGINE_COUNTERS = transfer.ENGINE_COUNTERS
 
 # the counters above that must stay 0 for a run to have stayed on its
 # device path (the chip smoke and the tests read them)
@@ -759,6 +768,9 @@ class QuiltRun(NamedTuple):
     host_edges: Optional[np.ndarray]
     host_stats: Optional[QuiltStats]
     sampler: str = "quilt"
+    # snode/dnode already fetched whole by the engine (quilt_run's keep
+    # mask, balldrop's host top-up): emission's fetches of them move nothing
+    nodes_fetched: bool = False
 
     @property
     def graphs_per_sample(self) -> int:
@@ -772,6 +784,7 @@ class QuiltRun(NamedTuple):
         kept = int(self.keep.sum()) if self.keep is not None else 0
         return kept + sum(int(p.shape[0]) for _, p in self.tail)
 
+    @tracing.traced("quilt.emit")
     def edges(self) -> np.ndarray:
         """Concatenated (E, 2) int64 edge array (all samples, sample-major)."""
         if self.host_edges is not None:
@@ -782,13 +795,14 @@ class QuiltRun(NamedTuple):
             return np.concatenate(self.edges_per_sample(), axis=0)
         pieces: List[np.ndarray] = []
         if self.keep is not None and self.keep.any():
-            sn = jax.device_get(self.snode)
-            dn = jax.device_get(self.dnode)
+            sn = transfer.to_host(self.snode, moves=not self.nodes_fetched)
+            dn = transfer.to_host(self.dnode, moves=not self.nodes_fetched)
             pieces.append(
                 np.stack(
                     [sn[self.keep], dn[self.keep]], axis=1
                 ).astype(np.int64)
             )
+            ENGINE_COUNTERS["kept_edges"] += pieces[0].shape[0]
         pieces.extend(p for _, p in self.tail)
         pieces = [p for p in pieces if p.size]
         if not pieces:
@@ -814,6 +828,7 @@ class QuiltRun(NamedTuple):
             tail=[p for _, p in self.tail],
         )
 
+    @tracing.traced("quilt.emit")
     def edges_per_sample(self) -> List[np.ndarray]:
         """Split the kept edges of a fused batch back into per-sample
         (E_s, 2) arrays (candidate order is sample-major, so each sample's
@@ -824,11 +839,12 @@ class QuiltRun(NamedTuple):
             return [self.host_edges]
         per: List[List[np.ndarray]] = [[] for _ in range(S)]
         if self.keep is not None and self.keep.any():
-            sn = jax.device_get(self.snode)
-            dn = jax.device_get(self.dnode)
+            sn = transfer.to_host(self.snode, moves=not self.nodes_fetched)
+            dn = transfer.to_host(self.dnode, moves=not self.nodes_fetched)
             idx = np.flatnonzero(self.keep)
             samp = (idx // max(self.slots_per_graph, 1)) // G
             dev = np.stack([sn[idx], dn[idx]], axis=1).astype(np.int64)
+            ENGINE_COUNTERS["kept_edges"] += dev.shape[0]
             bounds = np.searchsorted(samp, np.arange(1, S))
             for s, piece in enumerate(np.split(dev, bounds)):
                 per[s].append(piece)
@@ -874,6 +890,7 @@ class QuiltRun(NamedTuple):
         ]
 
 
+@tracing.traced("quilt.run")
 def quilt_run(
     key: jax.Array,
     plan: QuiltPlan,
@@ -974,7 +991,7 @@ def quilt_run(
         ask0 = budget
     elif targets is None:
         draws = (
-            jax.device_get(jax.random.normal(sub, (gtot,)))
+            transfer.to_host(jax.random.normal(sub, (gtot,)))
             * plan.std_edges
             + plan.mean_edges
         )
@@ -1070,45 +1087,54 @@ def quilt_run(
             # segmented dedup on-device, nothing returns to the host but the
             # per-graph counts
             rounds = rounds + (ask,)
-            while True:
-                try:
-                    chaos.maybe_fail("quilt.dispatch")
-                    fn = _compiled_round(
-                        mesh, axes, rounds, plan.B, use_kernel, len(tables),
-                        exact,
-                    )
-                    outs = dedup.call_x64(
-                        fn, rkey, gids_j, tpad_j, plan.cum, plan.thetas,
-                        tables,
-                    )
-                    break
-                except chaos.DeviceLoss as exc:
-                    # the device is gone — retrying the same program fails
-                    # identically, so rebuild over the survivors and re-run
-                    # the round (bit-exact, see _degrade_layout)
-                    mesh, axes, g_pad = _degrade_layout(mesh, exc, gtot)
-                    gids_j, tpad_j = _pad_inputs(gtot, g_pad, targets)
+            with tracing.span(
+                "quilt.round", round=r, ask=ask, slots=gtot * sum(rounds)
+            ):
+                while True:
+                    try:
+                        chaos.maybe_fail("quilt.dispatch")
+                        fn = _compiled_round(
+                            mesh, axes, rounds, plan.B, use_kernel,
+                            len(tables), exact,
+                        )
+                        outs = dedup.call_x64(
+                            fn, rkey, gids_j, tpad_j, plan.cum, plan.thetas,
+                            tables,
+                        )
+                        break
+                    except chaos.DeviceLoss as exc:
+                        # the device is gone — retrying the same program
+                        # fails identically, so rebuild over the survivors
+                        # and re-run the round (bit-exact, see
+                        # _degrade_layout)
+                        mesh, axes, g_pad = _degrade_layout(mesh, exc, gtot)
+                        gids_j, tpad_j = _pad_inputs(gtot, g_pad, targets)
             DISPATCH_COUNTERS[
                 "device_rounds" if r == 0 else "device_topup_rounds"
             ] += 1
-            counts = jax.device_get(outs[5]).astype(np.int64)[:gtot]
+            with tracing.span("quilt.round_wait", round=r):
+                # the wait for the round, apart from the copy of its counts
+                outs[5].block_until_ready()
+            counts = transfer.to_host(outs[5]).astype(np.int64)[:gtot]
             # exact mode has no shortfall concept: the thinning already
             # realized each cell's Bernoulli draw, counts ARE the result
             shortfall = np.zeros_like(targets) if exact else targets - counts
             if shortfall.max(initial=0) <= 0:
                 break
         a_tot = sum(rounds)
+        ENGINE_COUNTERS["candidate_slots"] += gtot * a_tot
 
     keep = None
     snode = dnode = None
     if outs is not None:
         scfg, dcfg, snode, dnode, take, _ = outs
-        take_h = jax.device_get(take)
-        keep = (
-            take_h
-            & (jax.device_get(snode) >= 0)
-            & (jax.device_get(dnode) >= 0)
-        )
+        with tracing.span("quilt.mask"):
+            take_h = transfer.to_host(take)
+            keep = (
+                take_h
+                & (transfer.to_host(snode) >= 0)
+                & (transfer.to_host(dnode) >= 0)
+            )
         if shortfall.max(initial=0) > 0:
             # pathological: max_rounds device rounds still short — fall back
             # to the PR-1 host rejection loop for the residual
@@ -1122,10 +1148,12 @@ def quilt_run(
                 "device-resident)",
             )
             flat_taken = (
-                jax.device_get(scfg)[take_h].astype(np.int64) * ncfg
-                + jax.device_get(dcfg)[take_h].astype(np.int64)
+                transfer.to_host(scfg)[take_h].astype(np.int64) * ncfg
+                + transfer.to_host(dcfg)[take_h].astype(np.int64)
             )
-            full_counts = jax.device_get(outs[5]).astype(np.int64)
+            full_counts = transfer.to_host(outs[5], moves=False).astype(
+                np.int64
+            )
             seen_cfg = list(
                 np.split(flat_taken, np.cumsum(full_counts)[:-1])
             )[:gtot]
@@ -1141,7 +1169,7 @@ def quilt_run(
         targets = counts.copy()
     return QuiltRun(
         plan, S, targets, counts, snode, dnode, keep, a_tot, tuple(tail),
-        None, None,
+        None, None, nodes_fetched=outs is not None,
     )
 
 
@@ -1762,10 +1790,10 @@ def split_run(
                 sp.blk_src_base, sp.blk_dst_base, sp.blk_alpha,
                 sp.blk_cumw,
             )
-            keep = jax.device_get(take)
+            keep = transfer.to_host(take)
             if keep.any():
-                sn = jax.device_get(src)[keep]
-                dn = jax.device_get(dst)[keep]
+                sn = transfer.to_host(src)[keep]
+                dn = transfer.to_host(dst)[keep]
                 pieces.append(
                     np.stack([sn, dn], axis=1).astype(np.int64)
                 )
