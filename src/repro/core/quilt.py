@@ -38,8 +38,10 @@ across a device mesh:
 3. **Descent + lookup + dedup** — one fused program per round draws the
    candidates for ALL local block pairs: quadrant descent produces config
    ids (Pallas kernel ``kernels/quadrant_descent.descent_prng`` on TPU, its
-   bit-identical jnp twin elsewhere), an XLA gather maps them to node ids
-   (:func:`gather_nodes`) with -1 marking a membership miss, then the sort-based
+   bit-identical jnp twin elsewhere) and maps them to node ids, -1 marking
+   a membership miss: inside the kernel, from the graph's two rows of the
+   dense inverse in VMEM, where the rows fit (:func:`kernel_lookup`), else
+   with an XLA gather (:func:`gather_nodes`); then the sort-based
    segmented dedup (core/dedup.py) over ``(graph_id << 2d) | src << d | dst``
    packed keys returns a fixed-shape take mask + per-graph unique counts.
 4. **Mesh sharding** — with ``mesh=``, the B^2 graphs are placed along the
@@ -461,6 +463,18 @@ def gather_nodes(tables, kb, lb, scfg, dcfg, d: int):
     return one(kb, scfg), one(lb, dcfg)
 
 
+def kernel_lookup(tables, use_kernel: bool) -> bool:
+    """Whether a round looks its configs up inside the descent kernel: it
+    runs the Pallas kernel, the plan has the dense inverse, and the
+    inverse's rows hold at most ``ops.KERNEL_LOOKUP_MAX_ROW`` entries.
+    Every other round gathers with :func:`gather_nodes`."""
+    return (
+        use_kernel
+        and len(tables) == 1
+        and tables[0].shape[1] <= ops.KERNEL_LOOKUP_MAX_ROW
+    )
+
+
 def graph_major(gids: jax.Array, a_tot: int):
     """(local graph index, global graph id) of every candidate of a
     graph-major round of ``a_tot`` slots per graph — broadcasts, not
@@ -647,10 +661,13 @@ def _round_body(
     Returns fixed-shape (scfg, dcfg, snode, dnode, take, counts); call under
     dedup.call_x64.  ``use_kernel`` picks the descent: the Pallas kernel
     (which derives the variates in-kernel — no HBM uniforms operand) or its
-    jnp twin; the two are bit-identical by shared integer math.  Either way
-    ``tables`` (:func:`device_lookup`) maps configs to nodes with an XLA
-    gather.  No collectives: with shard_map, the caller's gather of the
-    outputs is the only cross-device step.
+    jnp twin; the two are bit-identical by shared integer math.  ``tables``
+    (:func:`device_lookup`) maps configs to nodes: inside the kernel, from
+    the graph's two rows of the dense inverse held in VMEM, where
+    :func:`kernel_lookup` says the rows fit; else with an XLA gather
+    (:func:`gather_nodes`).  Both give the same ids bit for bit.  No
+    collectives: with shard_map, the caller's gather of the outputs is the
+    only cross-device step.
 
     ``exact=True`` is the exact-cell mode (single round, plan-constant
     budget): instead of ranking first-N-distinct cells against a drawn
@@ -665,19 +682,26 @@ def _round_body(
     a_tot = int(sum(rounds))
     seed = ops.counter_seed(rkey)
     local, gid = graph_major(gids, a_tot)
-    if use_kernel:
-        scfg, dcfg = ops.descent_prng_pallas(seed, gids, cum, a_tot=a_tot)
+    if kernel_lookup(tables, use_kernel):
+        scfg, dcfg, snode, dnode = ops.descent_prng_pallas(
+            seed, gids, cum, a_tot=a_tot, num_blocks=num_blocks,
+            inv=tables[0],
+        )
     else:
-        slot = jnp.arange(gc * a_tot, dtype=jnp.int32) - local * a_tot
-        u = ops.descent_uniforms(seed[0, 0], seed[0, 1], gid, slot, d)
-        scfg, dcfg = kpgm._descend(u, cum)
-    # graph ids beyond B^2 are batched samples (repro.api sample_batch):
-    # sample s's block pair g' lives at gid = s * B^2 + g', so the block
-    # decode reduces mod B^2 (a no-op for the single-sample gid < B^2 case)
-    block = gid % (num_blocks * num_blocks)
-    kb = block // num_blocks
-    lb = block % num_blocks
-    snode, dnode = gather_nodes(tables, kb, lb, scfg, dcfg, d)
+        if use_kernel:
+            scfg, dcfg = ops.descent_prng_pallas(seed, gids, cum, a_tot=a_tot)
+        else:
+            slot = jnp.arange(gc * a_tot, dtype=jnp.int32) - local * a_tot
+            u = ops.descent_uniforms(seed[0, 0], seed[0, 1], gid, slot, d)
+            scfg, dcfg = kpgm._descend(u, cum)
+        # graph ids beyond B^2 are batched samples (repro.api sample_batch):
+        # sample s's block pair g' lives at gid = s * B^2 + g', so the block
+        # decode reduces mod B^2 (a no-op for the single-sample gid < B^2
+        # case); the kernel's lookup decodes the same way
+        block = gid % (num_blocks * num_blocks)
+        kb = block // num_blocks
+        lb = block % num_blocks
+        snode, dnode = gather_nodes(tables, kb, lb, scfg, dcfg, d)
     cum_asks = jnp.arange(1, gc + 1, dtype=jnp.int32) * a_tot
     valid = None
     if exact:
@@ -1123,6 +1147,8 @@ def quilt_run(
                 break
         a_tot = sum(rounds)
         ENGINE_COUNTERS["candidate_slots"] += gtot * a_tot
+        if kernel_lookup(tables, use_kernel):
+            ENGINE_COUNTERS["kernel_lookup_slots"] += gtot * a_tot
 
     keep = None
     snode = dnode = None

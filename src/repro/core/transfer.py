@@ -4,8 +4,10 @@ the engines' work.
 Every transfer a sampler call makes goes through :func:`to_host`, inside a
 ``quilt.copy`` span.  :data:`ENGINE_COUNTERS`, read as ``quilt.<name>``
 through repro.tracing: ``candidate_slots`` drawn by the device rounds,
-``kept_edges`` emitted from the candidate buffers (counted from the
-emitted arrays' lengths), ``d2h_bytes`` moved by :func:`to_host`.
+``kernel_lookup_slots``, those of them whose config -> node lookup ran
+inside the descent kernel (``quilt.kernel_lookup``), ``kept_edges``
+emitted from the candidate buffers (counted from the emitted arrays'
+lengths), ``d2h_bytes`` moved by :func:`to_host`.
 """
 
 from __future__ import annotations
@@ -18,7 +20,9 @@ from repro import tracing
 __all__ = ["ENGINE_COUNTERS", "to_host"]
 
 ENGINE_COUNTERS = tracing.register(
-    "quilt", {"candidate_slots": 0, "kept_edges": 0, "d2h_bytes": 0}
+    "quilt",
+    {"candidate_slots": 0, "kernel_lookup_slots": 0, "kept_edges": 0,
+     "d2h_bytes": 0},
 )
 
 

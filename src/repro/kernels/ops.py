@@ -8,7 +8,7 @@ default backend is a TPU).
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +37,7 @@ counter_u01 = _qd.counter_u01
 counter_rank = _qd.counter_rank
 descent_uniforms = _qd.descent_uniforms
 rank_pair = _qd.rank_pair
+KERNEL_LOOKUP_MAX_ROW = _qd.KERNEL_LOOKUP_MAX_ROW
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0.0) -> jax.Array:
@@ -128,15 +129,20 @@ def descent_prng_pallas(
     a_tot: int,
     num_blocks: int = 1,
     ranks: bool = False,
+    inv: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, ...]:
     """Counter-PRNG descent kernel of the quilt/balldrop/KPGM rounds.
 
     The kernel derives (graph, slot, uniforms[, block ranks]) from its grid
     position, the (1, 2) seed and the (gc,) graph ids, and returns
-    ``(src_cfg, dst_cfg[, kb, lb])`` per candidate; the engines map the
-    configs to nodes with an XLA gather.  Bit-identical to the jnp twin
-    assembled from :func:`descent_uniforms` / :func:`rank_pair` (the kernel
-    path/jnp path parity tests rely on it).
+    ``(src_cfg, dst_cfg[, kb, lb])`` per candidate.  Given the plan's dense
+    (B, W) inverse ``inv``, W at most :data:`KERNEL_LOOKUP_MAX_ROW`, it
+    looks the configs up itself, in each graph's two block rows held in
+    VMEM, and returns ``(src_cfg, dst_cfg, src_node, dst_node)``; without
+    it the engines map configs to nodes with an XLA gather.  Bit-identical
+    to the jnp twin assembled from :func:`descent_uniforms` /
+    :func:`rank_pair` and that gather (the kernel path/jnp path parity
+    tests rely on it).
     """
     return _qd.descent_prng(
         seed,
@@ -146,6 +152,7 @@ def descent_prng_pallas(
         num_blocks=num_blocks,
         ranks=ranks,
         interpret=INTERPRET,
+        inv=inv,
     )
 
 

@@ -26,9 +26,14 @@ and seed are scalar-prefetched, and the (d, 4) table sits in SMEM.  The
 hash is plain uint32 arithmetic, so the same kernel body lowers in CPU
 interpret mode AND on TPU, and the jnp twins (``core/quilt.py`` /
 ``core/balldrop.py`` with ``use_kernel=False``) reproduce it bit-for-bit.
-The config -> node lookup is not in the kernel: the engines gather through
-the plan's tables in XLA.  A TPU-native variant using ``pltpu.prng_seed`` /
-``pltpu.prng_random_bits`` sits behind the ``tpu_native`` flag (no CPU
+Given the plan's dense config -> node inverse with rows of at most
+``KERNEL_LOOKUP_MAX_ROW`` entries, the kernel also looks the configs up:
+each grid step holds its graph's source and target block rows in VMEM and
+gathers from them along lanes (``_row_lookup``).  Other rounds (larger
+rows, the by-config tables, ball dropping's per-candidate blocks) gather
+through the plan's tables in XLA.  A TPU-native variant using
+``pltpu.prng_seed`` / ``pltpu.prng_random_bits`` sits behind the
+``tpu_native`` flag (no CPU
 lowering exists for those primitives; see docs/API.md for the flag +
 counter-derivation contract).  ``tests/test_chip_compile.py`` compiles the
 kernels for a v5e.
@@ -37,6 +42,7 @@ kernels for a v5e.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -367,9 +373,34 @@ def _rows_for(a_tot: int) -> int:
     return min(MAX_ROWS, need + (-need) % 8)
 
 
+def _row_lookup(tab_ref, cfg: jax.Array) -> jax.Array:
+    """Node id of each config of the ``cfg`` tile in the (1, R, 128) table
+    row ``tab_ref`` (entry ``x`` at row ``x >> 7``, lane ``x & 127``).
+
+    Mosaic gathers only within a tile of the same shape, along lanes, so
+    every 128-entry row slice is broadcast to the tile's shape, gathered at
+    each config's lane, and kept where the config's row is that slice's.
+    The loop walks the table a whole (8, 128) tile at a time, so every
+    load is aligned."""
+    hi = cfg >> 7
+    lane = cfg & (LANES - 1)
+
+    def tile(t, out):
+        block = tab_ref[0, pl.ds(pl.multiple_of(t * 8, 8), 8), :]
+        for s in range(8):
+            row = jnp.broadcast_to(block[s : s + 1, :], cfg.shape)
+            hit = jnp.take_along_axis(row, lane, axis=1)
+            out = jnp.where(hi == t * 8 + s, hit, out)
+        return out
+
+    return jax.lax.fori_loop(
+        0, tab_ref.shape[1] // 8, tile, jnp.full(cfg.shape, -1, jnp.int32)
+    )
+
+
 def _descent_kernel(
-    gids_ref, seed_ref, cum_ref, *out_refs, d: int, rows: int,
-    num_blocks: int, ranks: bool, native: bool,
+    gids_ref, seed_ref, cum_ref, *refs, d: int, rows: int,
+    num_blocks: int, ranks: bool, native: bool, lookup: bool,
 ):
     """Counter-PRNG quadrant descent for one (graph, slot-tile) grid step.
 
@@ -378,13 +409,20 @@ def _descent_kernel(
     channel k of the slot's counter word, and the config ids accumulate a
     bit per level — the same integers the jnp twin gets from
     :func:`descent_uniforms` + ``kpgm._descend``.  With ``ranks=True`` the
-    two reserved rank channels are emitted too (ball dropping).
+    two reserved rank channels are emitted too (ball dropping).  With
+    ``lookup=True`` the first two of ``refs`` are the inverse's rows of the
+    graph's source and target blocks in VMEM, and the node ids of both
+    configs are emitted too (:func:`_row_lookup`).
 
     ``native=True`` draws every channel from the TPU's hardware PRNG
     (``pltpu.prng_random_bits``, seeded per grid step) instead of the
     counter hash: a deployment-speed option with the same law but NOT the
     same bits, and no interpret-mode lowering.
     """
+    if lookup:
+        stab_ref, dtab_ref, *out_refs = refs
+    else:
+        out_refs = refs
     g = pl.program_id(0)
     j = pl.program_id(1)
     gid = gids_ref[g]
@@ -429,6 +467,26 @@ def _descent_kernel(
     if ranks:
         out_refs[2][...] = rank(_RANK0)
         out_refs[3][...] = rank(_RANK0 + 1)
+    if lookup:
+        out_refs[2][...] = _row_lookup(stab_ref, scfg)
+        out_refs[3][...] = _row_lookup(dtab_ref, dcfg)
+
+
+# Longest inverse row the descent kernel looks configs up in: the lookup
+# costs a few vector ops per 128-entry row slice per tile, so its time grows
+# with the row, while an XLA gather costs the same per element whatever
+# the table (PERF.md, section 6, gives the crossover measured on a v5e).
+KERNEL_LOOKUP_MAX_ROW = 1 << 16
+
+
+def _table_rows(inv: jax.Array) -> jax.Array:
+    """The (B, W) inverse as (B, R, 128) rows of whole (8, 128) tiles,
+    padded with -1 (configs never reach the padding)."""
+    bsz, width = inv.shape
+    pad = -width % (8 * LANES)
+    inv = jnp.pad(inv.astype(jnp.int32), ((0, 0), (0, pad)),
+                  constant_values=-1)
+    return inv.reshape(bsz, -1, LANES)
 
 
 @functools.partial(
@@ -445,6 +503,7 @@ def descent_prng(
     ranks: bool = False,
     interpret: bool = True,
     native: bool = False,
+    inv: Optional[jax.Array] = None,
 ):
     """Counter-PRNG quadrant descent over ``gids.size * a_tot`` candidates.
 
@@ -453,15 +512,21 @@ def descent_prng(
       gids:       (gc,) or (gc, 1) int32 GLOBAL graph ids of this shard.
       cumprobs:   (d, 4) cumulative quadrant probabilities.
       a_tot:      static slots per graph (cumulative over top-up rounds).
-      num_blocks: B — rank range of the ``ranks=True`` channels.
+      num_blocks: B — rank range of the ``ranks=True`` channels, and the
+                  block count of ``inv``.
+      inv:        optional (B, W) int32 dense config -> node inverse, -1
+                  where a block lacks the config, W at most
+                  :data:`KERNEL_LOOKUP_MAX_ROW`.
 
-    Returns ``(src_cfg, dst_cfg)`` — plus ``(kb, lb)`` when ``ranks`` —
-    each (gc * a_tot,) int32 in graph-major order, bit-identical to the jnp
-    twin built from :func:`descent_uniforms` / :func:`rank_pair`.  The only
-    HBM inputs are the seed, the graph ids and the table; the per-block
-    config -> node lookup is an XLA gather in the caller.  Each graph's
-    slots are padded to whole ``rows x 128`` tiles inside the kernel and
-    the padding is sliced off here.
+    Returns ``(src_cfg, dst_cfg)`` — plus ``(kb, lb)`` when ``ranks``, or
+    ``(src_node, dst_node)`` when ``inv`` is given — each (gc * a_tot,)
+    int32 in graph-major order, bit-identical to the jnp twin built from
+    :func:`descent_uniforms` / :func:`rank_pair` and the quilt's gather.
+    With ``inv``, graph g's block pair is ``(block // B, block % B)`` for
+    ``block = gid % B^2``, and the kernel looks the configs up in those
+    two rows of ``inv``, held in VMEM for all of the graph's grid steps.
+    Each graph's slots are padded to whole ``rows x 128`` tiles inside the
+    kernel and the padding is sliced off here.
     """
     gc = int(gids.shape[0])
     d = int(cumprobs.shape[0])
@@ -474,7 +539,13 @@ def descent_prng(
             f"{a_pad} slots per graph overflow the uint32 counter word "
             f"(limit {PRNG_SLOT_LIMIT})"
         )
-    nout = 4 if ranks else 2
+    if inv is not None and (ranks or inv.shape[1] > KERNEL_LOOKUP_MAX_ROW):
+        raise ValueError(
+            "the in-kernel lookup takes no rank channels and rows of at most "
+            f"KERNEL_LOOKUP_MAX_ROW={KERNEL_LOOKUP_MAX_ROW} entries "
+            f"(got {inv.shape[1]})"
+        )
+    nout = 4 if ranks or inv is not None else 2
     # traced with x64 off: the engines call this under dedup.call_x64, and
     # Mosaic refuses the 64-bit block indices x64 would give the index maps
     with jax.enable_x64(False):
@@ -482,6 +553,7 @@ def descent_prng(
             gids.reshape(gc).astype(jnp.int32),
             seed.reshape(2).astype(jnp.int32),
             cumprobs.astype(jnp.float32),
+            None if inv is None else _table_rows(inv),
             gc=gc, d=d, rows=rows, bpg=bpg, nout=nout,
             num_blocks=num_blocks, ranks=ranks, interpret=interpret,
             native=native,
@@ -490,21 +562,38 @@ def descent_prng(
 
 
 def _descent_call(
-    gids, seed, cumprobs, *, gc, d, rows, bpg, nout, num_blocks, ranks,
-    interpret, native,
+    gids, seed, cumprobs, table, *, gc, d, rows, bpg, nout, num_blocks,
+    ranks, interpret, native,
 ):
     from jax.experimental.pallas import tpu as pltpu
 
     a_pad = bpg * rows * LANES
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
+    operands = [cumprobs]
+    if table is not None:
+        # one block row per side, chosen by the graph id: consecutive grid
+        # steps of a graph keep the block index, so each row is fetched
+        # once per graph
+        pairs = num_blocks * num_blocks
+        row = (1, table.shape[1], LANES)
+        in_specs += [
+            pl.BlockSpec(
+                row, lambda g, j, gids, _: (gids[g] % pairs // num_blocks, 0, 0)
+            ),
+            pl.BlockSpec(
+                row, lambda g, j, gids, _: (gids[g] % pairs % num_blocks, 0, 0)
+            ),
+        ]
+        operands += [table, table]
     return pl.pallas_call(
         functools.partial(
             _descent_kernel, d=d, rows=rows, num_blocks=num_blocks,
-            ranks=ranks, native=native,
+            ranks=ranks, native=native, lookup=table is not None,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(gc, bpg),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
+            in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec(
                     (rows, LANES), lambda g, j, *_: (g * bpg + j, 0)
@@ -517,7 +606,7 @@ def _descent_call(
             for _ in range(nout)
         ],
         interpret=interpret,
-    )(gids, seed, cumprobs)
+    )(gids, seed, *operands)
 
 
 @functools.partial(

@@ -78,31 +78,35 @@ def _compile(fn, *args, x64=False):
 
 
 @pytest.mark.parametrize(
-    "ranks,gc,a_tot",
-    [(False, GRAPHS, A_TOT), (True, 1, 7_990_894)],
-    ids=["quilt", "balldrop"],
+    "ranks,gc,a_tot,lookup",
+    [(False, GRAPHS, A_TOT, False), (True, 1, 7_990_894, False),
+     (False, GRAPHS, A_TOT, True)],
+    ids=["quilt", "balldrop", "quilt_lookup"],
 )
-def test_descent_kernel_compiles_for_v5e(one_chip, ranks, gc, a_tot):
-    """The counter-PRNG descent kernel of the quilt round (64 graphs) and
-    of the ball-dropping round (one sample, rank channels on)."""
+def test_descent_kernel_compiles_for_v5e(one_chip, ranks, gc, a_tot, lookup):
+    """The counter-PRNG descent kernel of the quilt round (64 graphs), of
+    the ball-dropping round (one sample, rank channels on) and of the quilt
+    round that looks configs up in the kernel, in the (B, 2^d) inverse."""
 
-    def fn(seed, gids, cum):
+    def fn(seed, gids, cum, *inv):
         return qd.descent_prng(
-            seed, gids, cum, a_tot=a_tot, num_blocks=8, ranks=ranks,
-            interpret=False,
+            seed, gids, cum, a_tot=a_tot, num_blocks=B, ranks=ranks,
+            interpret=False, inv=inv[0] if inv else None,
         )
 
+    inv = (_spec(one_chip, (B, 1 << D), jnp.int32),) if lookup else ()
     # the engines dispatch their rounds under the x64 context
     compiled = _compile(
         fn,
         _spec(one_chip, (1, 2), jnp.int32),
         _spec(one_chip, (gc,), jnp.int32),
         _spec(one_chip, (D, 4), jnp.float32),
+        *inv,
         x64=True,
     )
     assert "tpu_custom_call" in compiled.as_text()
     out = compiled.memory_analysis().output_size_in_bytes
-    assert out >= (4 if ranks else 2) * gc * a_tot * 4
+    assert out >= (4 if ranks or lookup else 2) * gc * a_tot * 4
 
 
 def test_single_graph_descent_compiles_for_v5e(one_chip):
