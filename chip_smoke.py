@@ -257,11 +257,12 @@ def run_four_chips(args, jax, np) -> None:
     t_run = time.perf_counter() - t0
     mesh_edges = run.edges()
     per_dev = {}
-    for shard in run.snode.addressable_shards:
+    (part,) = run.parts
+    for shard in part.snode.addressable_shards:
         sl = shard.index[0]
         rows = int(shard.data.shape[0])
         graphs = rows // run.slots_per_graph
-        per_dev[shard.device.id] = (rows, int(run.keep[sl].sum()))
+        per_dev[shard.device.id] = (rows, int(part.keep[sl].sum()))
         say(
             "mesh",
             f"device {shard.device.id} ({shard.device.device_kind}): "

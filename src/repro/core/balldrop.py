@@ -429,8 +429,8 @@ def balldrop_run(
             bprime=None,
         )
         return quilt.QuiltRun(
-            plan, 1, targets, np.zeros(S, np.int64), None, None, None,
-            0, (), edges, st, sampler="balldrop",
+            plan, 1, targets, np.zeros(S, np.int64), (), 0, (), edges, st,
+            sampler="balldrop",
         )
 
     tail: List[Tuple[int, np.ndarray]] = []
@@ -496,8 +496,7 @@ def balldrop_run(
         a_tot = sum(rounds)
         transfer.ENGINE_COUNTERS["candidate_slots"] += S * a_tot
 
-    keep = None
-    snode = dnode = None
+    parts = ()
     fetched = False
     if outs is not None:
         snode, dnode, take, _ = outs
@@ -505,6 +504,7 @@ def balldrop_run(
         # rows are accepted balls: keep == take (and counts == keep sums)
         with tracing.span("quilt.mask"):
             keep = transfer.to_host(take)
+        parts = (quilt.RoundBuffers(0, snode, dnode, keep),)
         if shortfall.max(initial=0) > 0:
             quilt.fallback(
                 DISPATCH_COUNTERS,
@@ -534,6 +534,6 @@ def balldrop_run(
     if exact:
         targets = counts.copy()
     return quilt.QuiltRun(
-        plan, S, targets, counts, snode, dnode, keep, a_tot, tuple(tail),
+        plan, S, targets, counts, parts, a_tot, tuple(tail),
         None, None, sampler="balldrop", nodes_fetched=fetched,
     )

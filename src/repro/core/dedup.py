@@ -40,6 +40,7 @@ the packed keys, nothing else).
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Optional, Tuple
 
 import jax
@@ -56,6 +57,7 @@ __all__ = [
     "dedup_edges",
     "rechunk_edges",
     "iter_edge_chunks",
+    "kept_rows",
     "segmented_unique_mask",
     "segmented_unique",
     "call_x64",
@@ -203,33 +205,36 @@ def iter_edge_chunks(
     (``repro.api.MAGMSampler.sample_stream``): ``src``/``dst`` are the
     fixed-shape per-round candidate buffers (device or host arrays) and
     ``keep`` the host-side boolean take mask.  The buffers are walked in
-    windows — each window is sliced on device and only its kept rows reach
-    the host — so at no point does the full ``(E, 2)`` edge list
-    materialize.  ``tail`` pieces (host top-up edges) are appended after the
-    device edges, matching the concatenated-array emission order exactly.
-    Yields ``(chunk_edges, 2)`` int64 arrays (final chunk may be shorter).
+    windows (:func:`kept_rows`), so at no point does the full ``(E, 2)``
+    edge list materialize.  ``tail`` pieces (host top-up edges) are
+    appended after the device edges, matching the concatenated-array
+    emission order exactly.  Yields ``(chunk_edges, 2)`` int64 arrays
+    (final chunk may be shorter).
     """
+    return rechunk_edges(
+        itertools.chain(kept_rows(src, dst, keep, chunk_edges), tail),
+        chunk_edges,
+    )
 
-    def pieces():
-        window = max(int(chunk_edges), 1 << 15)
-        for (base, s_blk), (_, d_blk) in zip(
-            _row_blocks(src), _row_blocks(dst)
-        ):
-            for off in range(0, s_blk.shape[0], window):
-                with tracing.span("stream.window"):
-                    hi = min(off + window, s_blk.shape[0])
-                    k = keep[base + off : base + hi]
-                    if not k.any():
-                        continue
-                    s = to_host(s_blk[off : off + window])[k]
-                    d = to_host(d_blk[off : off + window])[k]
-                    piece = np.stack([s, d], axis=1)
-                    ENGINE_COUNTERS["kept_edges"] += piece.shape[0]
-                yield piece
-        for t in tail:
-            yield t
 
-    return rechunk_edges(pieces(), chunk_edges)
+def kept_rows(src, dst, keep: np.ndarray, chunk_edges: int):
+    """Yield the kept ``(src, dst)`` rows of one candidate buffer as
+    ``(E_i, 2)`` pieces, in row order: each window of at least
+    ``chunk_edges`` rows is sliced where the buffer lives (on the device,
+    or on the host) and only its kept rows reach the host."""
+    window = max(int(chunk_edges), 1 << 15)
+    for (base, s_blk), (_, d_blk) in zip(_row_blocks(src), _row_blocks(dst)):
+        for off in range(0, s_blk.shape[0], window):
+            with tracing.span("stream.window"):
+                hi = min(off + window, s_blk.shape[0])
+                k = keep[base + off : base + hi]
+                if not k.any():
+                    continue
+                s = to_host(s_blk[off : off + window])[k]
+                d = to_host(d_blk[off : off + window])[k]
+                piece = np.stack([s, d], axis=1)
+                ENGINE_COUNTERS["kept_edges"] += piece.shape[0]
+            yield piece
 
 
 def _row_blocks(x):

@@ -71,12 +71,14 @@ deprecated shims pinned bit-identical to the sessions; see docs/API.md.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import hashlib
+import itertools
 import math
 import warnings
 from collections import OrderedDict
-from typing import List, NamedTuple, Optional, Tuple
+from typing import Any, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -496,16 +498,27 @@ def slots_fit(a_tot: int) -> bool:
     return int(a_tot) <= ops.PRNG_SLOT_LIMIT
 
 
-def _pad_inputs(gtot: int, g_pad: int, targets: np.ndarray):
-    """(gids, targets) padded to ``g_pad`` as device arrays; padding rows
-    carry gid 0 / target 0, so they never emit.  Transfers are explicit
-    (``device_put``) so the hot path stays clean under
-    ``jax.transfer_guard("disallow")``."""
+def _pad_inputs(stop: int, g_pad: int, targets: np.ndarray, start: int = 0):
+    """(gids, targets) of graphs ``start .. stop - 1``, padded to ``g_pad``
+    as device arrays; padding rows carry gid 0 / target 0, so they never
+    emit.  Transfers are explicit (``device_put``) so the hot path stays
+    clean under ``jax.transfer_guard("disallow")``."""
     gids = np.zeros(g_pad, dtype=np.int32)
-    gids[:gtot] = np.arange(gtot, dtype=np.int32)
+    gids[: stop - start] = np.arange(start, stop, dtype=np.int32)
     tpad = np.zeros(g_pad, dtype=np.int32)
-    tpad[:gtot] = targets
+    tpad[: stop - start] = targets[start:stop]
     return jax.device_put(gids), jax.device_put(tpad)
+
+
+def split_graphs(gtot: int, slots: int) -> Tuple[int, int]:
+    """(sub-rounds, graphs a sub-round) of a round of ``gtot`` graphs of
+    ``slots`` candidates each: as few sub-rounds of whole graphs as keep
+    each within ``kpgm.DEVICE_MAX_CANDIDATES``, balanced so that one
+    program shape serves them all (the last is padded).  It reads
+    ``gtot`` and ``slots`` alone, so every mesh splits alike."""
+    per = max(kpgm.DEVICE_MAX_CANDIDATES // max(int(slots), 1), 1)
+    rounds = max(-(-int(gtot) // per), 1)
+    return rounds, -(-int(gtot) // rounds)
 
 
 def _exact_budget(p_max: Optional[float], mean_edges: float) -> Optional[int]:
@@ -763,15 +776,31 @@ class DeviceBatchUnavailable(RuntimeError):
     back to a per-sample loop."""
 
 
+class RoundBuffers(NamedTuple):
+    """The candidate buffers of one device round, or of one sub-round of
+    a split exact-cell round: graphs ``gid0`` on, ``slots_per_graph``
+    rows each, padding rows last."""
+
+    gid0: int  # global id of the first graph
+    # candidate node ids: device arrays, or their host copies once a later
+    # sub-round needed the device memory
+    snode: Any
+    dnode: Any
+    keep: np.ndarray  # host bool: taken AND both lookups hit
+
+
 class QuiltRun(NamedTuple):
     """One executed quilting run: fixed-shape device buffers + emission.
 
     The engine result shared by every public surface: ``edges()`` is the
     classic concatenated array, ``iter_chunks()`` the streaming emission
     (``repro.api.MAGMSampler.sample_stream``), ``edges_per_sample()`` the
-    fused-batch split.  ``tail`` holds ``(graph_id, (E, 2))`` pieces from
-    the pathological host top-up fallback, appended after the device edges
-    in insertion order; ``host_edges``/``host_stats`` are set instead of the
+    fused-batch split.  ``parts`` holds the :class:`RoundBuffers` of the
+    device round, one a sub-round of a split round, in graph order; each
+    emits its kept rows in candidate order, so edges come block pair by
+    block pair.  ``tail`` holds ``(graph_id, (E, 2))`` pieces from the
+    pathological host top-up fallback, appended after the device edges in
+    insertion order; ``host_edges``/``host_stats`` are set instead of the
     device fields when the run took the host backend.
 
     ``sampler`` records which engine produced the run: ``"quilt"`` (B^2
@@ -784,9 +813,7 @@ class QuiltRun(NamedTuple):
     num_samples: int
     targets: np.ndarray  # (num_samples * B^2,)
     counts: np.ndarray  # (num_samples * B^2,) per-graph unique counts
-    snode: Optional[jax.Array]  # (g_pad * slots,) candidate node ids
-    dnode: Optional[jax.Array]
-    keep: Optional[np.ndarray]  # host bool: taken AND both lookups hit
+    parts: Tuple[RoundBuffers, ...]
     slots_per_graph: int
     tail: Tuple[Tuple[int, np.ndarray], ...]
     host_edges: Optional[np.ndarray]
@@ -805,8 +832,18 @@ class QuiltRun(NamedTuple):
     def kept_edges(self) -> int:
         if self.host_edges is not None:
             return int(self.host_edges.shape[0])
-        kept = int(self.keep.sum()) if self.keep is not None else 0
+        kept = sum(int(p.keep.sum()) for p in self.parts)
         return kept + sum(int(p.shape[0]) for _, p in self.tail)
+
+    def _kept(self, part: RoundBuffers, rows=None) -> np.ndarray:
+        """(E, 2) int64 node pairs of a part's kept rows (``rows``, or the
+        keep mask itself)."""
+        sn = transfer.to_host(part.snode, moves=not self.nodes_fetched)
+        dn = transfer.to_host(part.dnode, moves=not self.nodes_fetched)
+        rows = part.keep if rows is None else rows
+        piece = np.stack([sn[rows], dn[rows]], axis=1).astype(np.int64)
+        ENGINE_COUNTERS["kept_edges"] += piece.shape[0]
+        return piece
 
     @tracing.traced("quilt.emit")
     def edges(self) -> np.ndarray:
@@ -817,16 +854,7 @@ class QuiltRun(NamedTuple):
             # tail pieces land after ALL device edges; only the per-sample
             # split reassembles a sample-major order for fused batches
             return np.concatenate(self.edges_per_sample(), axis=0)
-        pieces: List[np.ndarray] = []
-        if self.keep is not None and self.keep.any():
-            sn = transfer.to_host(self.snode, moves=not self.nodes_fetched)
-            dn = transfer.to_host(self.dnode, moves=not self.nodes_fetched)
-            pieces.append(
-                np.stack(
-                    [sn[self.keep], dn[self.keep]], axis=1
-                ).astype(np.int64)
-            )
-            ENGINE_COUNTERS["kept_edges"] += pieces[0].shape[0]
+        pieces = [self._kept(p) for p in self.parts if p.keep.any()]
         pieces.extend(p for _, p in self.tail)
         pieces = [p for p in pieces if p.size]
         if not pieces:
@@ -840,16 +868,13 @@ class QuiltRun(NamedTuple):
             raise ValueError("iter_chunks streams single-sample runs only")
         if self.host_edges is not None:
             return dedup.rechunk_edges([self.host_edges], chunk_edges)
-        if self.keep is None:
-            return dedup.rechunk_edges(
-                [p for _, p in self.tail], chunk_edges
-            )
-        return dedup.iter_edge_chunks(
-            self.snode,
-            self.dnode,
-            self.keep,
-            chunk_edges,
-            tail=[p for _, p in self.tail],
+        pieces = [
+            dedup.kept_rows(p.snode, p.dnode, p.keep, chunk_edges)
+            for p in self.parts
+        ]
+        pieces.append(p for _, p in self.tail)
+        return dedup.rechunk_edges(
+            itertools.chain.from_iterable(pieces), chunk_edges
         )
 
     @tracing.traced("quilt.emit")
@@ -862,14 +887,13 @@ class QuiltRun(NamedTuple):
         if self.host_edges is not None:
             return [self.host_edges]
         per: List[List[np.ndarray]] = [[] for _ in range(S)]
-        if self.keep is not None and self.keep.any():
-            sn = transfer.to_host(self.snode, moves=not self.nodes_fetched)
-            dn = transfer.to_host(self.dnode, moves=not self.nodes_fetched)
-            idx = np.flatnonzero(self.keep)
-            samp = (idx // max(self.slots_per_graph, 1)) // G
-            dev = np.stack([sn[idx], dn[idx]], axis=1).astype(np.int64)
-            ENGINE_COUNTERS["kept_edges"] += dev.shape[0]
-            bounds = np.searchsorted(samp, np.arange(1, S))
+        for part in self.parts:
+            if not part.keep.any():
+                continue
+            idx = np.flatnonzero(part.keep)
+            graph = part.gid0 + idx // max(self.slots_per_graph, 1)
+            dev = self._kept(part, idx)
+            bounds = np.searchsorted(graph // G, np.arange(1, S))
             for s, piece in enumerate(np.split(dev, bounds)):
                 per[s].append(piece)
         for g, piece in self.tail:
@@ -946,12 +970,15 @@ def quilt_run(
     instead of the first-N-distinct law ``1 - (1 - p/S)^N`` whose high-Q
     deficit the MAGFIT recovery suite surfaced.  The round shape is a plan
     constant — warm sessions re-dispatch one cached program for every key
-    (zero recompiles).  Runs that cannot take it (explicit targets, host
-    backend, budget past DEVICE_MAX_CANDIDATES) fall back to the legacy
-    ranked rounds, counted in ``DISPATCH_COUNTERS["exact_fallbacks"]``;
-    ``exact_cells=False`` forces the legacy path (the KPGM sessions do, to
-    keep their drawn-target contract).  ``QuiltRun.targets`` equals the
-    realized counts in exact mode.
+    (zero recompiles).  A round over DEVICE_MAX_CANDIDATES runs as
+    sub-rounds of whole block pairs (:func:`split_graphs`), one program
+    shape for all, bit-identical to one round: the counter stream is keyed
+    by the global graph id.  A run with no finite budget (G alone past
+    DEVICE_MAX_CANDIDATES or the counter-PRNG slot limit) falls back to the
+    legacy ranked rounds, counted in ``DISPATCH_COUNTERS["exact_fallbacks"]``;
+    explicit targets take them too, and ``exact_cells=False`` forces them
+    (the KPGM sessions do, to keep their drawn-target contract).
+    ``QuiltRun.targets`` equals the realized counts in exact mode.
 
     ``backend="balldrop"`` dispatches to the ball-dropping engine
     (core/balldrop.py, arXiv:1202.6001): same plan, same QuiltRun surface,
@@ -996,16 +1023,14 @@ def quilt_run(
         and gtot > 0
     )
     budget = plan.exact_budget if exact else None
-    if exact and (
-        budget is None or gtot * budget > kpgm.DEVICE_MAX_CANDIDATES
-    ):
+    if exact and budget is None:
         fallback(
             DISPATCH_COUNTERS,
             "exact_fallbacks",
-            f"exact-cell round over the device budget ({gtot} graphs x "
-            f"{budget} proposals > DEVICE_MAX_CANDIDATES="
-            f"{kpgm.DEVICE_MAX_CANDIDATES}, or no finite budget): taking "
-            "the drawn-target rounds instead",
+            "exact-cell round over the device budget: no finite proposal "
+            "budget a block pair within DEVICE_MAX_CANDIDATES="
+            f"{kpgm.DEVICE_MAX_CANDIDATES} and the counter-PRNG slot limit: "
+            "taking the drawn-target rounds instead",
         )
         exact = False
 
@@ -1034,7 +1059,10 @@ def quilt_run(
 
     from repro.dist import sharding as _dist_sharding
 
-    layout = _dist_sharding.graph_layout(mesh, gtot)
+    # an exact round over DEVICE_MAX_CANDIDATES runs as sub-rounds of
+    # whole block pairs; drawn-target rounds grow by top-ups, never split
+    nsub, per_sub = split_graphs(gtot, budget) if exact else (1, gtot)
+    layout = _dist_sharding.graph_layout(mesh, per_sub)
     axes, g_pad = layout.axes, layout.padded
     if not axes:
         mesh = None  # no usable graph axis: run the unsharded program
@@ -1075,114 +1103,143 @@ def quilt_run(
             key, plan, max_rounds=max_rounds, oversample=oversample
         )
         return QuiltRun(
-            plan, 1, targets, np.zeros(gtot, np.int64), None, None, None,
-            0, (), edges, st,
+            plan, 1, targets, np.zeros(gtot, np.int64), (), 0, (), edges, st,
         )
 
     tail: List[Tuple[int, np.ndarray]] = []
     counts = np.zeros(gtot, dtype=np.int64)
     seen_cfg: Optional[List[np.ndarray]] = None
-    outs = None
+    parts: List[RoundBuffers] = []
     shortfall = targets.copy()
     key, rkey = jax.random.split(key)
     a_tot = 0
 
-    if total > 0:
-        gids_j, tpad_j = _pad_inputs(gtot, g_pad, targets)
-        tables = lookup
-        rounds: Tuple[int, ...] = ()
-        for r in range(1 if exact else max_rounds):
-            chaos.maybe_fail("quilt.round")
-            ask = budget if exact else dedup.uniform_ask(shortfall, oversample)
-            if ask == 0:
-                break
-            if rounds and (
-                gtot * (sum(rounds) + ask) > kpgm.DEVICE_MAX_CANDIDATES
-                or not slots_fit(sum(rounds) + ask)
-            ):
-                # the cumulative stream would outgrow the device budget
-                # (near-saturated targets): let the host fallback finish the
-                # residual instead of OOMing.  Like the backend decision,
-                # this guard is layout-invariant (gtot * total, no nshards),
-                # so every mesh breaks at the same round with the same state.
-                break
-            # each dispatch re-processes [prior rounds || fresh draws] as one
-            # longer per-graph stream: the seen keys are carried through the
-            # segmented dedup on-device, nothing returns to the host but the
-            # per-graph counts
-            rounds = rounds + (ask,)
-            with tracing.span(
-                "quilt.round", round=r, ask=ask, slots=gtot * sum(rounds)
-            ):
-                while True:
-                    try:
-                        chaos.maybe_fail("quilt.dispatch")
-                        fn = _compiled_round(
-                            mesh, axes, rounds, plan.B, use_kernel,
-                            len(tables), exact,
-                        )
-                        outs = dedup.call_x64(
-                            fn, rkey, gids_j, tpad_j, plan.cum, plan.thetas,
-                            tables,
-                        )
-                        break
-                    except chaos.DeviceLoss as exc:
-                        # the device is gone — retrying the same program
-                        # fails identically, so rebuild over the survivors
-                        # and re-run the round (bit-exact, see
-                        # _degrade_layout)
-                        mesh, axes, g_pad = _degrade_layout(mesh, exc, gtot)
-                        gids_j, tpad_j = _pad_inputs(gtot, g_pad, targets)
-            DISPATCH_COUNTERS[
-                "device_rounds" if r == 0 else "device_topup_rounds"
-            ] += 1
-            with tracing.span("quilt.round_wait", round=r):
-                # the wait for the round, apart from the copy of its counts
-                outs[5].block_until_ready()
-            counts = transfer.to_host(outs[5]).astype(np.int64)[:gtot]
-            # exact mode has no shortfall concept: the thinning already
-            # realized each cell's Bernoulli draw, counts ARE the result
-            shortfall = np.zeros_like(targets) if exact else targets - counts
-            if shortfall.max(initial=0) <= 0:
-                break
-        a_tot = sum(rounds)
-        ENGINE_COUNTERS["candidate_slots"] += gtot * a_tot
-        if kernel_lookup(tables, use_kernel):
-            ENGINE_COUNTERS["kernel_lookup_slots"] += gtot * a_tot
+    split = (
+        tracing.span(
+            "quilt.split", rounds=nsub, graphs_per_round=per_sub,
+            pad=nsub * per_sub - gtot,
+        )
+        if nsub > 1
+        else contextlib.nullcontext()
+    )
+    with split:
+        for subround in range(nsub if total > 0 else 0):
+            lo = subround * per_sub
+            hi = min(lo + per_sub, gtot)
+            ng = hi - lo
+            gids_j, tpad_j = _pad_inputs(hi, g_pad, targets, lo)
+            rounds: Tuple[int, ...] = ()
+            outs = None
+            for r in range(1 if exact else max_rounds):
+                chaos.maybe_fail("quilt.round")
+                ask = (
+                    budget if exact
+                    else dedup.uniform_ask(shortfall[lo:hi], oversample)
+                )
+                if ask == 0:
+                    break
+                if rounds and (
+                    ng * (sum(rounds) + ask) > kpgm.DEVICE_MAX_CANDIDATES
+                    or not slots_fit(sum(rounds) + ask)
+                ):
+                    # the cumulative stream would outgrow the device budget
+                    # (near-saturated targets): let the host fallback finish
+                    # the residual instead of OOMing.  Like the backend
+                    # decision, this guard is layout-invariant (no nshards),
+                    # so every mesh breaks at the same round with the same
+                    # state.
+                    break
+                # each dispatch re-processes [prior rounds || fresh draws] as
+                # one longer per-graph stream: the seen keys are carried
+                # through the segmented dedup on-device, nothing returns to
+                # the host but the per-graph counts
+                rounds = rounds + (ask,)
+                with tracing.span(
+                    "quilt.round", round=r, subround=subround, ask=ask,
+                    slots=ng * sum(rounds),
+                ):
+                    while True:
+                        try:
+                            chaos.maybe_fail("quilt.dispatch")
+                            fn = _compiled_round(
+                                mesh, axes, rounds, plan.B, use_kernel,
+                                len(lookup), exact,
+                            )
+                            outs = dedup.call_x64(
+                                fn, rkey, gids_j, tpad_j, plan.cum,
+                                plan.thetas, lookup,
+                            )
+                            break
+                        except chaos.DeviceLoss as exc:
+                            # the device is gone — retrying the same program
+                            # fails identically, so rebuild over the
+                            # survivors and re-run the round (bit-exact, see
+                            # _degrade_layout)
+                            mesh, axes, g_pad = _degrade_layout(
+                                mesh, exc, per_sub
+                            )
+                            gids_j, tpad_j = _pad_inputs(
+                                hi, g_pad, targets, lo
+                            )
+                DISPATCH_COUNTERS[
+                    "device_rounds" if r == 0 else "device_topup_rounds"
+                ] += 1
+                ENGINE_COUNTERS["dispatched_slots"] += g_pad * sum(rounds)
+                with tracing.span("quilt.round_wait", round=r):
+                    # the wait for the round, apart from the copy of its
+                    # counts
+                    outs[5].block_until_ready()
+                counts[lo:hi] = transfer.to_host(outs[5]).astype(np.int64)[:ng]
+                # exact mode has no shortfall concept: the thinning already
+                # realized each cell's Bernoulli draw, counts ARE the result
+                shortfall[lo:hi] = (
+                    0 if exact else targets[lo:hi] - counts[lo:hi]
+                )
+                if shortfall[lo:hi].max(initial=0) <= 0:
+                    break
+            a_tot = sum(rounds)
+            ENGINE_COUNTERS["candidate_slots"] += ng * a_tot
+            if kernel_lookup(lookup, use_kernel):
+                ENGINE_COUNTERS["kernel_lookup_slots"] += ng * a_tot
+            if outs is None:
+                continue
 
-    keep = None
-    snode = dnode = None
-    if outs is not None:
-        scfg, dcfg, snode, dnode, take, _ = outs
-        with tracing.span("quilt.mask"):
-            take_h = transfer.to_host(take)
-            keep = (
-                take_h
-                & (transfer.to_host(snode) >= 0)
-                & (transfer.to_host(dnode) >= 0)
-            )
-        if shortfall.max(initial=0) > 0:
-            # pathological: max_rounds device rounds still short — fall back
-            # to the PR-1 host rejection loop for the residual
-            fallback(
-                DISPATCH_COUNTERS,
-                "degraded_fallbacks",
-                f"device rounds exhausted (max_rounds={max_rounds}, "
-                f"{a_tot} slots/graph) with {int(shortfall.sum())} edges "
-                "still short: finishing the residual with the host "
-                "rejection loop (raise max_rounds or oversample to stay "
-                "device-resident)",
-            )
-            flat_taken = (
-                transfer.to_host(scfg)[take_h].astype(np.int64) * ncfg
-                + transfer.to_host(dcfg)[take_h].astype(np.int64)
-            )
-            full_counts = transfer.to_host(outs[5], moves=False).astype(
-                np.int64
-            )
-            seen_cfg = list(
-                np.split(flat_taken, np.cumsum(full_counts)[:-1])
-            )[:gtot]
+            scfg, dcfg, snode, dnode, take, _ = outs
+            with tracing.span("quilt.mask"):
+                take_h = transfer.to_host(take)
+                snode_h = transfer.to_host(snode)
+                dnode_h = transfer.to_host(dnode)
+                keep = take_h & (snode_h >= 0) & (dnode_h >= 0)
+            if subround < nsub - 1:
+                # the next sub-round needs the device memory: keep this
+                # one's node ids on the host and drop its device buffers
+                parts.append(RoundBuffers(lo, snode_h, dnode_h, keep))
+                del outs, scfg, dcfg, snode, dnode, take
+                continue
+            parts.append(RoundBuffers(lo, snode, dnode, keep))
+            if shortfall.max(initial=0) > 0:
+                # pathological: max_rounds device rounds still short — fall
+                # back to the PR-1 host rejection loop for the residual (a
+                # drawn-target run, so this is its one round)
+                fallback(
+                    DISPATCH_COUNTERS,
+                    "degraded_fallbacks",
+                    f"device rounds exhausted (max_rounds={max_rounds}, "
+                    f"{a_tot} slots/graph) with {int(shortfall.sum())} "
+                    "edges still short: finishing the residual with the "
+                    "host rejection loop (raise max_rounds or oversample "
+                    "to stay device-resident)",
+                )
+                flat_taken = (
+                    transfer.to_host(scfg)[take_h].astype(np.int64) * ncfg
+                    + transfer.to_host(dcfg)[take_h].astype(np.int64)
+                )
+                full_counts = transfer.to_host(outs[5], moves=False).astype(
+                    np.int64
+                )
+                seen_cfg = list(
+                    np.split(flat_taken, np.cumsum(full_counts)[:-1])
+                )[:gtot]
 
     if seen_cfg is not None:
         counts = _host_quilt_topup(
@@ -1194,8 +1251,8 @@ def quilt_run(
         # "targets" of an exact run
         targets = counts.copy()
     return QuiltRun(
-        plan, S, targets, counts, snode, dnode, keep, a_tot, tuple(tail),
-        None, None, nodes_fetched=outs is not None,
+        plan, S, targets, counts, tuple(parts), a_tot, tuple(tail),
+        None, None, nodes_fetched=bool(parts),
     )
 
 

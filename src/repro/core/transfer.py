@@ -5,9 +5,11 @@ Every transfer a sampler call makes goes through :func:`to_host`, inside a
 ``quilt.copy`` span.  :data:`ENGINE_COUNTERS`, read as ``quilt.<name>``
 through repro.tracing: ``candidate_slots`` drawn by the device rounds,
 ``kernel_lookup_slots``, those of them whose config -> node lookup ran
-inside the descent kernel (``quilt.kernel_lookup``), ``kept_edges``
-emitted from the candidate buffers (counted from the emitted arrays'
-lengths), ``d2h_bytes`` moved by :func:`to_host`.
+inside the descent kernel (``quilt.kernel_lookup``), ``dispatched_slots``,
+the slots the quilt's round programs ran, padding rows included (a split
+round's padded last sub-round, mesh padding, a top-up's re-drawn
+prefix), ``kept_edges`` emitted from the candidate buffers (counted from
+the emitted arrays' lengths), ``d2h_bytes`` moved by :func:`to_host`.
 """
 
 from __future__ import annotations
@@ -21,8 +23,8 @@ __all__ = ["ENGINE_COUNTERS", "to_host"]
 
 ENGINE_COUNTERS = tracing.register(
     "quilt",
-    {"candidate_slots": 0, "kernel_lookup_slots": 0, "kept_edges": 0,
-     "d2h_bytes": 0},
+    {"candidate_slots": 0, "dispatched_slots": 0, "kernel_lookup_slots": 0,
+     "kept_edges": 0, "d2h_bytes": 0},
 )
 
 

@@ -135,9 +135,10 @@ def test_engine_counters_and_spans_on_sample_and_stream(samplers, kind, clean):
     key = jax.random.PRNGKey(5)
     targets = np.array([KPGM_EDGES]) if kind == "kpgm" else None
     run = sampler._run(key, targets=targets) if kind == "kpgm" else sampler._run(key)
-    assert run.snode.shape[0] > 32768
+    (part,) = run.parts
+    assert part.snode.shape[0] > 32768
     slots = run.plan.num_graphs * run.slots_per_graph
-    buffers = run.keep.nbytes + run.snode.nbytes + run.dnode.nbytes
+    buffers = part.keep.nbytes + part.snode.nbytes + part.dnode.nbytes
     _call(sampler, kind, "sample", key)  # compile every shape first
 
     moved = {}
@@ -215,8 +216,9 @@ def test_balldrop_counts_its_slots_and_copies_its_buffers_once(clean):
     # balldrop's mask is its take; snode and dnode reach the host in edges()
     assert d["quilt.candidate_slots"] == run.num_samples * run.slots_per_graph
     assert d["quilt.kept_edges"] == gs.num_edges > 0
+    (part,) = run.parts
     assert d["quilt.d2h_bytes"] == (
-        run.keep.nbytes + run.snode.nbytes + run.dnode.nbytes
+        part.keep.nbytes + part.snode.nbytes + part.dnode.nbytes
         + sum(r.attrs["bytes"] for r in counts)
     )
     assert d["balldrop.dispatch.device_rounds"] >= 1
@@ -260,8 +262,9 @@ def test_a_host_top_up_counts_each_buffer_once(engine, clean):
     # (float32) and the round's counts (int32), one per graph; take, snode
     # and dnode; and, for the quilt's top-up, its configuration buffers
     per_graph = 2 * 4 * run.targets.size
-    buffers = run.keep.size + run.snode.nbytes + run.dnode.nbytes
-    configs = 2 * run.snode.nbytes if engine == "quilt" else 0
+    (part,) = run.parts
+    buffers = part.keep.size + part.snode.nbytes + part.dnode.nbytes
+    configs = 2 * part.snode.nbytes if engine == "quilt" else 0
     assert sum(moved) == per_graph + buffers + configs
 
 
